@@ -404,8 +404,12 @@ energy_factors_batch = jax.vmap(energy_factors, in_axes=(0, None, None, None))
 
 def total_cf_from_factors(f: EnergyFactors, ci: jax.Array) -> jax.Array:
     """(N, 3) total CF rows under per-request CI rows ``ci`` (N, 5) — the
-    einsum replacing a full ``evaluate`` sweep per candidate region/hour."""
-    return jnp.einsum("ntc,nc->nt", f.op_unit, ci) + f.emb_cf.sum(-1)
+    einsum replacing a full ``evaluate`` sweep per candidate region/hour.
+    Full f32 precision: a TPU multiplies f32 operands in bf16 passes by
+    default, which would shift scores (and tie-breaks) off the CPU's."""
+    return (jnp.einsum("ntc,nc->nt", f.op_unit, ci,
+                       precision=jax.lax.Precision.HIGHEST)
+            + f.emb_cf.sum(-1))
 
 
 # --- Forecast-error risk on the factorized scorer ------------------------------

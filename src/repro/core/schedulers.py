@@ -130,6 +130,13 @@ def _with_bias(X: jax.Array) -> jax.Array:
     return jnp.concatenate([X, jnp.ones((X.shape[0], 1), X.dtype)], axis=1)
 
 
+def _dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """Scoring matmul at full f32 precision: a TPU multiplies f32 operands
+    in bf16 passes by default, which would move learned scores (and their
+    argmin) away from the CPU's."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 # Every learned scheduler splits into an offline ``fit_params(train)`` (numpy
 # or host-loop heavy lifting, unchanged math) and a pure-JAX
 # ``jax_scores(params, X) -> (N, 3)`` (lower is better) that
@@ -182,7 +189,8 @@ class RegressionScheduler:
         # feasibility from *known* per-target latency requirement is implicit
         # in the label; regression approximates it via predicted latency rank
         Xb = _with_bias(X)
-        return Xb @ params["W_cf"] + 10.0 * (Xb @ params["W_lat"] > 0.0)
+        return (_dot(Xb, params["W_cf"])
+                + 10.0 * (_dot(Xb, params["W_lat"]) > 0.0))
 
     def fit_predict(self, train, test) -> FitResult:
         params = self.fit_params(train)
@@ -245,9 +253,9 @@ class ClassificationScheduler:
     @staticmethod
     def jax_scores(params: dict, X: jax.Array) -> jax.Array:
         Xb = _with_bias(X)
-        s = -(Xb @ params["W"])  # argmin(-logit) = argmax(logit)
+        s = -_dot(Xb, params["W"])  # argmin(-logit) = argmax(logit)
         if "W_cf" in params:  # host-static: headless params skip the blend
-            s = s + params["head_w"] * (Xb @ params["W_cf"])
+            s = s + params["head_w"] * _dot(Xb, params["W_cf"])
         return s
 
     def fit_predict(self, train, test) -> FitResult:
@@ -312,9 +320,9 @@ class BOScheduler:
         # would be gigabytes; |a-b|^2 = |a|^2 + |b|^2 - 2ab stays (N, m).
         S = params["support"]
         d2 = ((X ** 2).sum(-1)[:, None] + (S ** 2).sum(-1)[None, :]
-              - 2.0 * X @ S.T)
+              - 2.0 * _dot(X, S.T))
         K = jnp.exp(-0.5 * jnp.maximum(d2, 0.0) / params["ls"] ** 2)
-        return K @ params["alpha"]
+        return _dot(K, params["alpha"])
 
     def fit_predict(self, train, test) -> FitResult:
         params = self.fit_params(train)
@@ -394,7 +402,7 @@ class RLScheduler:
         inter = (ci[:, :, None] * wf[:, None, :3]).reshape(X.shape[0], -1)
         phi = jnp.concatenate(
             [X, ci ** 2, inter, jnp.ones((X.shape[0], 1), X.dtype)], axis=1)
-        return phi @ params["W"]
+        return _dot(phi, params["W"])
 
     def fit_predict(self, train, test) -> FitResult:
         W = np.asarray(self.fit_params(train)["W"])

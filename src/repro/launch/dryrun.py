@@ -11,9 +11,12 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod] [--json out.json]
 """
 
-# The VERY FIRST lines — before ANY other import (jax locks the device count
-# on first init):
+# The VERY FIRST lines — before ANY other import (jax locks the platform and
+# the device count on first init). The dry run compiles for 512 fake CPU
+# devices by design, so it pins the CPU platform: on a machine with an
+# accelerator it must neither claim nor land on the chip.
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("XLA_FLAGS", ""))
 
@@ -121,19 +124,6 @@ def collective_bytes(hlo_text: str) -> dict[str, int]:
         out[base] += max(_shape_bytes(type_str), operand_bytes)
     out["total"] = sum(out[k] for k in _COLLECTIVE_KINDS)
     return out
-
-
-def normalize_cost_analysis(cost) -> dict:
-    """``Compiled.cost_analysis()`` returns a dict on recent JAX but a
-    list of per-computation dicts (possibly empty) on older releases —
-    normalize both shapes to one flat dict, summing duplicate keys."""
-    if isinstance(cost, dict):
-        return cost
-    merged: dict = {}
-    for entry in cost or ():
-        for k, v in entry.items():
-            merged[k] = merged.get(k, 0.0) + v
-    return merged
 
 
 # ---------------------------------------------------------------------------
@@ -278,7 +268,7 @@ def lower_cell(arch: str, shape_id: str, mesh, *,
 
         compiled = lowered.compile()
         res.compile_s = time.time() - t0
-        cost = normalize_cost_analysis(compiled.cost_analysis())
+        cost = compiled.cost_analysis()
         res.flops = float(cost.get("flops", 0.0))
         res.hlo_bytes = float(cost.get("bytes accessed", 0.0))
         mem = compiled.memory_analysis()

@@ -38,17 +38,19 @@ routes every stream through this module — ``serve_stream`` and the rolling
 re-planner ride it automatically through the ``_route_arrays`` seam.
 Measured on ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` CPU
 meshes; pinned in the device-scaling section of
-``benchmarks/policy_throughput.py``.
+``benchmarks/policy_throughput.py``. ``chip_smoke.py --chips 4`` checks the
+same parity on a 4-chip TPU host.
 """
 
 from __future__ import annotations
 
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -66,29 +68,33 @@ from repro.serve.temporal import TemporalState
 DATA_AXIS = "data"
 
 
-def enable_compile_cache(cache_dir: str | None = None) -> str:
-    """Wire jax's persistent compilation cache at ``cache_dir`` (default
-    ``~/.cache/repro-jit``, overridable via ``REPRO_COMPILE_CACHE``) so the
-    big sharded admission jits compile once across process restarts.
+#: the compile cache's fixed home when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: one directory inside the checkout (git-ignored), so every process
+#: of every run finds the programs the last one compiled.
+DEFAULT_COMPILE_CACHE = str(
+    Path(__file__).resolve().parents[3] / ".jax_compile_cache")
 
-    The thresholds are dropped to zero: the routing programs are few and
-    large, so caching everything is strictly a win (a warm start skips the
-    multi-second while-loop admission compile entirely — cold/warm timings
-    are pinned in the README). Returns the directory in use."""
-    if cache_dir is None:
-        cache_dir = os.environ.get(
-            "REPRO_COMPILE_CACHE",
-            os.path.join(os.path.expanduser("~"), ".cache", "repro-jit"))
-    os.makedirs(cache_dir, exist_ok=True)
+
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache so the big admission jits
+    compile once across process restarts.
+
+    The cache lives where ``JAX_COMPILATION_CACHE_DIR`` says when it is set
+    (no other directory is ever chosen then), and in
+    ``DEFAULT_COMPILE_CACHE`` otherwise. The thresholds are dropped to zero:
+    the routing programs are few and large, so caching everything is
+    strictly a win. Returns the directory in use."""
+    cache_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                 or DEFAULT_COMPILE_CACHE)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
+    os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     # jax latches the cache state (including "disabled: no dir configured")
     # at the FIRST compile in the process — which import-time jnp ops have
     # usually already triggered by the time this runs. Reset so the next
     # compile re-initializes against the directory configured above.
-    from jax._src import compilation_cache as _cc
-    _cc.reset_cache()
+    compilation_cache.reset_cache()
     return cache_dir
 
 
@@ -117,7 +123,7 @@ def _build_sharded_route(fr, mesh: Mesh, axis: str):
     mirrors ``FleetRouter._fleet_route`` but returns PER-ROW arrays only
     (aggregation happens on the host, deterministically in the device
     count). Replicated outputs are returned device-tiled (leading axis
-    ``D``) because ``check_rep=False`` — required for the admission
+    ``D``) because ``check_vma=False`` — required for the admission
     while-loops — forbids unmentioned-axis out_specs."""
     policy = fr.policy
     infra = fr._infra
@@ -229,8 +235,8 @@ def _build_sharded_route(fr, mesh: Mesh, axis: str):
                        "ref_oracle"), row_spec),
         dict.fromkeys(("counts", "shed_pair"), row_spec),
     )
-    sharded = shard_map(_local, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+    sharded = jax.shard_map(_local, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
     # donate the big per-row request buffers (workload columns, avail,
     # region/hour/slack tags): routing consumes the stream in place — at
     # 10M requests that is the difference between one and two resident
@@ -250,25 +256,15 @@ def _program_for(fr, mesh: Mesh, axis: str, sig):
     return cache[key]
 
 
-def route_arrays_sharded(fr, batch, region_np, hour_np, mesh, *,
-                         ci_fc=None, cap_scale=None, used0=None,
-                         slack_np=None):
-    """Sharded twin of ``FleetRouter._route_arrays`` — same prepared-array
-    contract, same ``(FleetRouteResult, state)`` return, decisions
-    bit-identical to the single-device program at any device count.
-
-    Host side: sort the stream by the policy's admission-segment key, pad
-    to a device multiple with unroutable max-key dummies, shard
-    contiguously; run the shard_map program; slice the pads off, unsort,
-    and aggregate per-row outputs with numpy."""
-    policy = fr.policy
-    if isinstance(policy, CapacityLimiter):
-        raise NotImplementedError(
-            "CapacityLimiter's lax.scan admission walks windows "
-            "sequentially per device and cannot reconcile caps across a "
-            "sharded stream — use PlacementPolicy (identity adjacency "
-            "reproduces CapacityLimiter bit-for-bit) on the sharded path")
+def shard_stream(fr, batch, region_np, hour_np, mesh, slack_np=None):
+    """Host half of the sharded call: sort the stream by the policy's
+    admission-segment key, pad it to a device multiple with unroutable
+    max-key dummies, and place it contiguously over the mesh axis. Returns
+    ``(rows, inv_np)``: the per-row program inputs (workload, avail,
+    region, hour, slack — each a ``NamedSharding`` over the mesh) and the
+    inverse permutation that puts per-row outputs back in stream order."""
     axis = _check_mesh(mesh)
+    policy = fr.policy
     n_devices = int(mesh.devices.size)
     n = len(batch)
     n_regions = len(fr.regions)
@@ -297,21 +293,46 @@ def route_arrays_sharded(fr, batch, region_np, hour_np, mesh, *,
     batch_s = slice_batch(batch, order_np, n_pad)
     pad = lambda a, fill: np.concatenate(
         [a[order_np], np.full((n_pad - n,), fill, a.dtype)])
-    region_s = pad(region_np, n_regions - 1)
-    hour_s = pad(hour_np, fr._horizon_h - 1)
     slack_base = np.asarray(
         batch.slack_h if slack_np is None else slack_np, np.int32)
-    slack_s = pad(slack_base, 0)
+    shard = NamedSharding(mesh, P(axis))
+    rows = jax.device_put(
+        (batch_s.workload(fr.cfg), batch_s.avail,
+         pad(region_np, n_regions - 1), pad(hour_np, fr._horizon_h - 1),
+         pad(slack_base, 0)), shard)
+    return rows, inv_np
+
+
+def route_arrays_sharded(fr, batch, region_np, hour_np, mesh, *,
+                         ci_fc=None, cap_scale=None, used0=None,
+                         slack_np=None):
+    """Sharded twin of ``FleetRouter._route_arrays`` — same prepared-array
+    contract, same ``(FleetRouteResult, state)`` return, decisions
+    bit-identical to the single-device program at any device count.
+
+    Host side: ``shard_stream`` sorts, pads and shards the stream; run the
+    shard_map program; slice the pads off, unsort, and aggregate per-row
+    outputs with numpy."""
+    policy = fr.policy
+    if isinstance(policy, CapacityLimiter):
+        raise NotImplementedError(
+            "CapacityLimiter's lax.scan admission walks windows "
+            "sequentially per device and cannot reconcile caps across a "
+            "sharded stream — use PlacementPolicy (identity adjacency "
+            "reproduces CapacityLimiter bit-for-bit) on the sharded path")
+    axis = _check_mesh(mesh)
+    n = len(batch)
+    n_regions = len(fr.regions)
+    region_np = np.asarray(region_np, np.int32)
+    rows, inv_np = shard_stream(fr, batch, region_np, hour_np, mesh,
+                                slack_np)
 
     # --- run the sharded program ------------------------------------------
     sig = (ci_fc is None, cap_scale is None, used0 is None)
     program = _program_for(fr, mesh, axis, sig)
-    shard = NamedSharding(mesh, P(axis))
-    put = lambda tree: jax.device_put(tree, shard)
     per_row, tiled = program(
-        put(batch_s.workload(fr.cfg)), put(batch_s.avail),
-        put(region_s), put(hour_s), put(slack_s), fr._ci_table,
-        fr._ci_fc if ci_fc is None else ci_fc, cap_scale, used0)
+        *rows, fr._ci_table, fr._ci_fc if ci_fc is None else ci_fc,
+        cap_scale, used0)
 
     # --- unpad + unsort + host-side aggregation ---------------------------
     row = lambda a: None if a is None else np.asarray(a)[:n][inv_np]

@@ -307,10 +307,14 @@ class OraclePolicy(RoutingPolicy):
         ``extra_latency=None`` — no WAN hop anywhere — the hop-gating
         collapses away statically). Fallback semantics per candidate match
         ``scores_from_factors``."""
+        # full f32 precision (TPU default is bf16 passes): candidate
+        # scores must order the same on every backend
         hp = jnp.einsum("ntc,nc->nt", factors.op_unit[..., :2],
-                        home_ci[..., :2])  # (N, 3)
+                        home_ci[..., :2],
+                        precision=jax.lax.Precision.HIGHEST)  # (N, 3)
         cp = jnp.einsum("ntc,rnc->rnt", factors.op_unit[..., 2:],
-                        cand_ci_dc)  # (R, N, 3)
+                        cand_ci_dc,
+                        precision=jax.lax.Precision.HIGHEST)  # (R, N, 3)
         total_cf = hp[None] + cp + factors.emb_cf.sum(-1)[None]
         ok_base = carbon_model.qos_feasible_from_factors(factors, w) & avail
         any_base = jnp.any(ok_base, axis=-1, keepdims=True)  # (N, 1)
@@ -568,7 +572,8 @@ class LearnedPolicy(RoutingPolicy):
             scale = 1.0 / (100.0 * self.feat_std[_CI_DC_COLS])  # (3,)
             delta = (cand_ci_dc - home_ci[None, :, 2:]) * scale  # (R, N, 3)
             s = s0[None] + jnp.einsum("rnc,ct->rnt", delta,
-                                      self.ci_sens[_CI_DC_COLS])
+                                      self.ci_sens[_CI_DC_COLS],
+                                      precision=jax.lax.Precision.HIGHEST)
         else:
             def one_region(ci_dc):
                 ci_mixed = jnp.concatenate([home_ci[:, :2], ci_dc], axis=1)

@@ -685,6 +685,18 @@ class FleetRouter:
             return distributed.route_arrays_sharded(
                 self, batch, region_np, hour_np, mesh, ci_fc=ci_fc,
                 cap_scale=cap_scale, used0=used0, slack_np=slack_np)
+        return self._fleet_route(*self._route_args(
+            batch, region_np, hour_np, ci_fc=ci_fc, cap_scale=cap_scale,
+            used0=used0, slack_np=slack_np))
+
+    def _route_args(self, batch: RequestBatch, region_np: np.ndarray,
+                    hour_np: np.ndarray, *, ci_fc: jax.Array | None = None,
+                    cap_scale: jax.Array | None = None,
+                    used0: jax.Array | None = None,
+                    slack_np: np.ndarray | None = None) -> tuple:
+        """The single-device ``_fleet_route`` arguments for a prepared
+        stream (what ``_route_arrays`` calls it with; their shapes are what
+        an ahead-of-time compile for a described device needs)."""
         # stream-order hint: stable radix sort by arrival window — or by
         # (window, home region) when the policy wants finer segments
         # (tier-only PlacementPolicy) — on the host; only computed for
@@ -709,11 +721,9 @@ class FleetRouter:
         slack = jnp.asarray(batch.slack_h if slack_np is None else
                             np.asarray(slack_np, np.int32))
         state = self.policy.initial_state(len(self.regions), len(batch))
-        return self._fleet_route(batch.workload(self.cfg), batch.avail,
-                                 region, hour, self._ci_table,
-                                 self._ci_fc if ci_fc is None else ci_fc,
-                                 state, order, inv_order, slack,
-                                 cap_scale, used0)
+        return (batch.workload(self.cfg), batch.avail, region, hour,
+                self._ci_table, self._ci_fc if ci_fc is None else ci_fc,
+                state, order, inv_order, slack, cap_scale, used0)
 
     def route_stream_rolling(self, batch: RequestBatch, region: np.ndarray,
                              t_hours: np.ndarray, *, step_h: int = 6,

@@ -17,10 +17,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.compilation_cache import compilation_cache
 
 from repro.configs import get_config
 from repro.core import carbon_model
 from repro.core.carbon_intensity import DEFAULT_REGIONS, CarbonGrid
+from repro.serve.distributed import DEFAULT_COMPILE_CACHE
 from repro.serve import (
     BatchFormer,
     CapacityLimiter,
@@ -333,16 +335,44 @@ class TestBatchFormerMesh:
         assert drafts and all(fb.pad_to % 4 == 0 for fb in drafts)
 
 
-def test_enable_compile_cache_configures_jax(tmp_path):
-    old = jax.config.jax_compilation_cache_dir
-    try:
-        d = enable_compile_cache(str(tmp_path / "jit-cache"))
-        assert os.path.isdir(d)
-        assert jax.config.jax_compilation_cache_dir == d
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old)
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
+@pytest.fixture
+def restore_cache_config():
+    """Put jax's persistent-cache settings back after a test moved them."""
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    old = {k: getattr(jax.config, k) for k in keys}
+    yield
+    for k, v in old.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+
+
+def test_enable_compile_cache_configures_jax(tmp_path, monkeypatch,
+                                             restore_cache_config):
+    """``JAX_COMPILATION_CACHE_DIR`` wins: the cache is configured there
+    and a fresh compile writes its entry there."""
+    want = str(tmp_path / "jit-cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    d = enable_compile_cache()
+    assert d == want and os.path.isdir(d)
+    assert jax.config.jax_compilation_cache_dir == d
+    jax.block_until_ready(jax.jit(lambda x: x * 3.0 + 0.25)(jnp.ones(7)))
+    assert os.listdir(d), "the compile wrote no cache entry"
+
+
+def test_enable_compile_cache_default_in_checkout(monkeypatch,
+                                                  restore_cache_config):
+    """Without the variable the cache goes to ONE fixed, git-ignored
+    directory at the root of the checkout."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    d = enable_compile_cache()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert d == DEFAULT_COMPILE_CACHE
+    assert os.path.dirname(d) == root and os.path.isdir(d)
+    assert jax.config.jax_compilation_cache_dir == d
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert os.path.basename(d) + "/" in f.read().split()
 
 
 @pytest.mark.slow
@@ -401,6 +431,7 @@ print("SHARD_INVARIANCE_OK")
 """
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
                           text=True, timeout=560,
-                          env={**os.environ, "PYTHONPATH": "src"},
+                          env={**os.environ, "PYTHONPATH": "src",
+                               "JAX_PLATFORMS": "cpu"},
                           cwd=os.path.dirname(os.path.dirname(__file__)))
     assert "SHARD_INVARIANCE_OK" in proc.stdout, proc.stderr[-2000:]

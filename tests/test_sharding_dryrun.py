@@ -149,7 +149,7 @@ print("SUBPROCESS_OK", r.mesh)
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
                           text=True, timeout=560,
                           env={**__import__("os").environ,
-                               "PYTHONPATH": "src"},
+                               "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"},
                           cwd=__import__("os").path.dirname(
                               __import__("os").path.dirname(__file__)))
     assert "SUBPROCESS_OK 2x16x16" in proc.stdout, proc.stderr[-2000:]
