@@ -97,7 +97,7 @@ sparse stream re-routes through the ``shard_map`` path, bit-identical
 to the single-device program.
 
 Run:  PYTHONPATH=src python -m benchmarks.policy_throughput [--n 1000000]
-      [--devices 8] [--profile-dir /tmp/trace]
+      [--devices 8]
 """
 
 from __future__ import annotations
@@ -854,15 +854,8 @@ def main() -> None:
                          "local devices; use XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N for "
                          "fake CPU devices)")
-    ap.add_argument("--profile-dir", default=None,
-                    help="write a jax.profiler trace of the whole run "
-                         "here (view with TensorBoard / Perfetto)")
     args = ap.parse_args()
-    if args.profile_dir:
-        with jax.profiler.trace(args.profile_dir):
-            rows = run(args.n, args.reps, devices=args.devices)
-    else:
-        rows = run(args.n, args.reps, devices=args.devices)
+    rows = run(args.n, args.reps, devices=args.devices)
     print("name,us_per_call,derived")
     for row in rows:
         print(row.csv())

@@ -398,8 +398,10 @@ def energy_factors(w: Workload, infra: InfraParams, interference: jax.Array,
 
 
 #: (N,)-batched factorization — ONE evaluation for the whole stream; every
-#: (region, tier, hour) candidate score downstream is einsum + mask.
-energy_factors_batch = jax.vmap(energy_factors, in_axes=(0, None, None, None))
+#: (region, tier, hour) candidate score downstream is einsum + mask. Its
+#: device ops carry the ``factors`` scope.
+energy_factors_batch = jax.named_scope("factors")(
+    jax.vmap(energy_factors, in_axes=(0, None, None, None)))
 
 
 def total_cf_from_factors(f: EnergyFactors, ci: jax.Array) -> jax.Array:
@@ -482,6 +484,7 @@ def pair_qos_feasible_from_factors(f: EnergyFactors, w: Workload,
 stream_feasible_batch = jax.vmap(stream_feasible)
 
 
+@jax.named_scope("factors")
 def route_many_from_factors(f: EnergyFactors, w: Workload, ci: jax.Array,
                             avail: jax.Array) -> RouteOutputs:
     """``route_many_envs`` semantics rebuilt from precomputed factors + the

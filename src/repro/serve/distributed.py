@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import Mesh, NamedSharding
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from repro.core import carbon_model
@@ -171,58 +172,60 @@ def _build_sharded_route(fr, mesh: Mesh, axis: str):
             order=ident, inv_order=ident, slack=slack, factors=factors,
             fc_table=ci_fc, cap_scale=cap_scale, used0=used0,
             axis_name=axis)
-        shed = getattr(new_state, "shed", None)
-        exec_region = getattr(new_state, "exec_region", None)
-        exec_hour = getattr(new_state, "exec_hour", None)
-        if exec_region is None and exec_hour is None:
-            exec_region = region
-            carbon = take_act(targets)
-            feas = take2(out.ok, targets)
-        elif factors is not None:
-            er = region if exec_region is None else exec_region
-            eh = hour if exec_hour is None else exec_hour
-            exec_region = er
-            ci_exec = jnp.concatenate(
-                [ci_table[region, eh][:, :2],
-                 ci_table[er, eh][:, 2:]], axis=1)
-            cf_exec = carbon_model.total_cf_from_factors(factors, ci_exec)
-            ok_exec = carbon_model.qos_feasible_from_factors(
-                factors, w, rtt_s[region, er]) & avail
-            carbon = take2(cf_exec, targets)
-            feas = take2(ok_exec, targets)
-        else:
-            ci_exec = jnp.concatenate(
-                [ci_table[region, hour][:, :2],
-                 ci_table[exec_region, hour][:, 2:]], axis=1)
-            out_exec = carbon_model.route_many_envs(
-                w, infra,
-                Environment(ci=ci_exec, interference=interference,
-                            net_slowdown=net_slowdown), avail)
-            moved = exec_region != region
-            if shed is not None:
-                moved = moved & ~shed
-            carbon = jnp.where(moved, take2(out_exec.total_cf, targets),
-                               take_act(targets))
-            feas = jnp.where(moved, take2(out_exec.ok, targets),
-                             take2(out.ok, targets))
-        per_row = dict(
-            target=targets,
-            carbon=carbon,
-            feas=feas,
-            exec_region=exec_region,
-            shed=shed,
-            exec_hour=getattr(new_state, "exec_hour", None),
-            defer=getattr(new_state, "defer_hours", None),
-            ref_latency=take_act(out.target_latency),
-            ref_energy=take_act(out.target_energy),
-            ref_oracle=take_act(out.target),
-        )
+        with jax.named_scope("account"):
+            shed = getattr(new_state, "shed", None)
+            exec_region = getattr(new_state, "exec_region", None)
+            exec_hour = getattr(new_state, "exec_hour", None)
+            if exec_region is None and exec_hour is None:
+                exec_region = region
+                carbon = take_act(targets)
+                feas = take2(out.ok, targets)
+            elif factors is not None:
+                er = region if exec_region is None else exec_region
+                eh = hour if exec_hour is None else exec_hour
+                exec_region = er
+                ci_exec = jnp.concatenate(
+                    [ci_table[region, eh][:, :2],
+                     ci_table[er, eh][:, 2:]], axis=1)
+                cf_exec = carbon_model.total_cf_from_factors(factors, ci_exec)
+                ok_exec = carbon_model.qos_feasible_from_factors(
+                    factors, w, rtt_s[region, er]) & avail
+                carbon = take2(cf_exec, targets)
+                feas = take2(ok_exec, targets)
+            else:
+                ci_exec = jnp.concatenate(
+                    [ci_table[region, hour][:, :2],
+                     ci_table[exec_region, hour][:, 2:]], axis=1)
+                out_exec = carbon_model.route_many_envs(
+                    w, infra,
+                    Environment(ci=ci_exec, interference=interference,
+                                net_slowdown=net_slowdown), avail)
+                moved = exec_region != region
+                if shed is not None:
+                    moved = moved & ~shed
+                carbon = jnp.where(moved, take2(out_exec.total_cf, targets),
+                                   take_act(targets))
+                feas = jnp.where(moved, take2(out_exec.ok, targets),
+                                 take2(out.ok, targets))
+            per_row = dict(
+                target=targets,
+                carbon=carbon,
+                feas=feas,
+                exec_region=exec_region,
+                shed=shed,
+                exec_hour=getattr(new_state, "exec_hour", None),
+                defer=getattr(new_state, "defer_hours", None),
+                ref_latency=take_act(out.target_latency),
+                ref_energy=take_act(out.target_energy),
+                ref_oracle=take_act(out.target),
+            )
         # replicated state pieces, device-tiled for the out_spec (host
         # reads shard 0; parity across shards is exactly what the
         # reconciliation guarantees and the invariance suite pins)
         tiled = dict(
             counts=getattr(new_state, "counts", None),
             shed_pair=getattr(new_state, "shed_pair", None),
+            admit_rounds=getattr(new_state, "admit_rounds", None),
         )
         return per_row, jax.tree.map(lambda x: x[None], tiled)
 
@@ -233,7 +236,7 @@ def _build_sharded_route(fr, mesh: Mesh, axis: str):
         dict.fromkeys(("target", "carbon", "feas", "exec_region", "shed",
                        "exec_hour", "defer", "ref_latency", "ref_energy",
                        "ref_oracle"), row_spec),
-        dict.fromkeys(("counts", "shed_pair"), row_spec),
+        dict.fromkeys(("counts", "shed_pair", "admit_rounds"), row_spec),
     )
     sharded = jax.shard_map(_local, mesh=mesh, in_specs=in_specs,
                             out_specs=out_specs, check_vma=False)
@@ -312,7 +315,8 @@ def route_arrays_sharded(fr, batch, region_np, hour_np, mesh, *,
 
     Host side: ``shard_stream`` sorts, pads and shards the stream; run the
     shard_map program; slice the pads off, unsort, and aggregate per-row
-    outputs with numpy."""
+    outputs with numpy. Host spans: ``gs.route.prepare``,
+    ``gs.route.dispatch`` and ``gs.route.aggregate``."""
     policy = fr.policy
     if isinstance(policy, CapacityLimiter):
         raise NotImplementedError(
@@ -321,20 +325,27 @@ def route_arrays_sharded(fr, batch, region_np, hour_np, mesh, *,
             "sharded stream — use PlacementPolicy (identity adjacency "
             "reproduces CapacityLimiter bit-for-bit) on the sharded path")
     axis = _check_mesh(mesh)
-    n = len(batch)
-    n_regions = len(fr.regions)
     region_np = np.asarray(region_np, np.int32)
-    rows, inv_np = shard_stream(fr, batch, region_np, hour_np, mesh,
-                                slack_np)
+    with TraceAnnotation("gs.route.prepare"):
+        rows, inv_np = shard_stream(fr, batch, region_np, hour_np, mesh,
+                                    slack_np)
 
     # --- run the sharded program ------------------------------------------
-    sig = (ci_fc is None, cap_scale is None, used0 is None)
-    program = _program_for(fr, mesh, axis, sig)
-    per_row, tiled = program(
-        *rows, fr._ci_table, fr._ci_fc if ci_fc is None else ci_fc,
-        cap_scale, used0)
+    with TraceAnnotation("gs.route.dispatch"):
+        sig = (ci_fc is None, cap_scale is None, used0 is None)
+        program = _program_for(fr, mesh, axis, sig)
+        per_row, tiled = program(
+            *rows, fr._ci_table, fr._ci_fc if ci_fc is None else ci_fc,
+            cap_scale, used0)
+    with TraceAnnotation("gs.route.aggregate"):
+        return _aggregate(fr, policy, per_row, tiled, inv_np, region_np)
 
-    # --- unpad + unsort + host-side aggregation ---------------------------
+
+def _aggregate(fr, policy, per_row, tiled, inv_np, region_np):
+    """Unpad, unsort and aggregate the sharded program's per-row outputs
+    on the host into the ``(FleetRouteResult, state)`` pair."""
+    n = len(inv_np)
+    n_regions = len(fr.regions)
     row = lambda a: None if a is None else np.asarray(a)[:n][inv_np]
     target = row(per_row["target"])
     carbon = row(per_row["carbon"])
@@ -388,6 +399,7 @@ def _rebuild_state(policy, per_row, tiled, row):
         return ()
     counts = jnp.asarray(np.asarray(counts)[0])
     shed_pair = jnp.asarray(np.asarray(tiled["shed_pair"])[0])
+    admit_rounds = jnp.asarray(np.asarray(tiled["admit_rounds"])[0])
     shed = jnp.asarray(row(per_row["shed"]))
     if per_row["exec_hour"] is not None:
         return TemporalState(
@@ -395,10 +407,11 @@ def _rebuild_state(policy, per_row, tiled, row):
             exec_region=jnp.asarray(row(per_row["exec_region"])),
             shed_pair=shed_pair,
             exec_hour=jnp.asarray(row(per_row["exec_hour"])),
-            defer_hours=jnp.asarray(row(per_row["defer"])))
+            defer_hours=jnp.asarray(row(per_row["defer"])),
+            admit_rounds=admit_rounds)
     diag = bool(getattr(policy, "_diag_only", False))
     return PlacementState(
         counts=counts, shed=shed,
         exec_region=(None if diag
                      else jnp.asarray(row(per_row["exec_region"]))),
-        shed_pair=shed_pair)
+        shed_pair=shed_pair, admit_rounds=admit_rounds)

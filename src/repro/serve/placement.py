@@ -82,12 +82,18 @@ class PlacementState:
     ``shed_pair``   (R, 3) int32 — per-pair shed accounting: shed requests
                     keyed by their first-choice (region, tier) pair, i.e.
                     where the demand that could not be placed wanted to run.
+    ``admit_rounds`` () int32 — admission rounds run, summed over decisions
+                    like ``counts``: the skip-full ``while_loop``'s final
+                    trip count, or the static round count of the unrolled
+                    programs. Equal on every device of a sharded call (the
+                    loop condition is a global any).
     """
 
     counts: jax.Array
     shed: jax.Array
     exec_region: jax.Array | None
     shed_pair: jax.Array
+    admit_rounds: jax.Array
 
 
 def windowed_segment_ranks(choice: jax.Array, active: jax.Array,
@@ -363,13 +369,15 @@ class PlacementPolicy(RoutingPolicy):
             shed=jnp.zeros((n_requests,), bool),
             exec_region=(None if self._diag_only
                          else jnp.zeros((n_requests,), jnp.int32)),
-            shed_pair=jnp.zeros((n_regions, N_TARGETS), jnp.int32))
+            shed_pair=jnp.zeros((n_regions, N_TARGETS), jnp.int32),
+            admit_rounds=jnp.zeros((), jnp.int32))
 
     def scores(self, w, env, avail, *, hour=None):
         """The inner policy's home-region scores (same units); placement
         preference lives in ``pair_scores`` / the factorized variants."""
         return self.inner.scores(w, env, avail, hour=hour)
 
+    @jax.named_scope("score")
     def pair_scores(self, w, env, avail, home: jax.Array,
                     hour: jax.Array) -> jax.Array:
         """(N, R, 3) effective scores of every (region, tier) pair: the inner
@@ -418,6 +426,7 @@ class PlacementPolicy(RoutingPolicy):
         penalized = jnp.where(s >= 0.0, s * pen, s / pen)
         return jnp.where(allowed, penalized, jnp.inf)
 
+    @jax.named_scope("score")
     def pair_scores_from_factors(self, factors: EnergyFactors, w, env, avail,
                                  home: jax.Array, hour: jax.Array,
                                  fc_table: jax.Array | None = None
@@ -472,6 +481,7 @@ class PlacementPolicy(RoutingPolicy):
                               jnp.float32)
         return jax.vmap(one_region)(cand_ci_dc, extra)
 
+    @jax.named_scope("score")
     def sparse_pair_scores_from_factors(self, factors, w, env, avail,
                                         home: jax.Array, hour: jax.Array,
                                         fc_table: jax.Array | None = None
@@ -586,8 +596,9 @@ class PlacementPolicy(RoutingPolicy):
             # diagonal latency penalty scales a request's whole row by one
             # positive factor, which never reorders it — skip the multiply
             # so the scores stay bit-identical to CapacityLimiter's.
-            s = scores_with_reuse(self.inner, w, env, avail, hour,
-                                  outputs)  # (N, 3)
+            with jax.named_scope("score"):
+                s = scores_with_reuse(self.inner, w, env, avail, hour,
+                                      outputs)  # (N, 3)
             return self._decide_diag(s, win, home, order, inv, state,
                                      caps_rt, used0, axis_name)
         if getattr(self, "_sparse", False):
@@ -629,6 +640,7 @@ class PlacementPolicy(RoutingPolicy):
         return self._decide_cross_legacy(s, win, home, order, inv, state,
                                          caps_rt, used0, axis_name)
 
+    @jax.named_scope("admit")
     def _decide_diag(self, s, win, home, order, inv, state,
                      caps_rt=None, used0=None, axis_name=None):
         """Tier-only admission: the PR-2/PR-3 segment-rank program,
@@ -718,8 +730,10 @@ class PlacementPolicy(RoutingPolicy):
             # tier-only spill never leaves home: the None sentinel lets the
             # router skip the executed-region accounting entirely
             exec_region=None,
-            shed_pair=state.shed_pair + shed_pair)
+            shed_pair=state.shed_pair + shed_pair,
+            admit_rounds=state.admit_rounds + N_TARGETS)
 
+    @jax.named_scope("admit")
     def _decide_cross(self, s, win, home, order, inv, state,
                       caps_rt=None, used0=None, axis_name=None,
                       cand_pair=None):
@@ -816,20 +830,22 @@ class PlacementPolicy(RoutingPolicy):
                      else jnp.asarray(used0, jnp.float32).reshape(-1))
         placed0 = jnp.zeros((n,), bool)
         mask0 = open_mask(used_init, placed0)
-        _, _, used, placed, exec_pair, _ = jax.lax.while_loop(
+        _, _, used, placed, exec_pair, rounds = jax.lax.while_loop(
             cond, body,
             (_global_any(mask0.any(), axis_name), mask0, used_init, placed0,
              jnp.zeros((n,), jnp.int32), jnp.zeros((), jnp.int32)))
         return self._finalize_cross(s_s, home_s, routable, first_col,
                                     placed, exec_pair, used, inv, state,
-                                    used_init, axis_name,
+                                    rounds, used_init, axis_name,
                                     home_row_s=home_row_s)
 
+    @jax.named_scope("admit")
     def _finalize_cross(self, s_s, home_s, routable, first_col, placed,
-                        exec_pair, used, inv, state, used_init=None,
+                        exec_pair, used, inv, state, rounds, used_init=None,
                         axis_name=None, home_row_s=None):
         """Shared shed/fallback + back-to-stream-order tail of both
-        cross-region admission programs. Only *routable* leftovers are
+        cross-region admission programs (``rounds``: the admission rounds
+        the program ran). Only *routable* leftovers are
         capacity-shed; their nominal placement is the first-choice pair. A
         request with no finite-score pair at all was never a capacity
         decision — it takes the uncapped degenerate fallback on its HOME
@@ -869,8 +885,10 @@ class PlacementPolicy(RoutingPolicy):
             counts=state.counts + counts.astype(jnp.int32),
             shed=shed,
             exec_region=exec_region,
-            shed_pair=state.shed_pair + shed_pair)
+            shed_pair=state.shed_pair + shed_pair,
+            admit_rounds=state.admit_rounds + rounds)
 
+    @jax.named_scope("admit")
     def _decide_cross_legacy(self, s, win, home, order, inv, state,
                              caps_rt=None, used0=None, axis_name=None):
         """The PR-3 cross-region admission, kept verbatim for inner
@@ -898,7 +916,8 @@ class PlacementPolicy(RoutingPolicy):
         used = used_init
         placed = jnp.zeros((n,), bool)
         exec_pair = jnp.zeros((n,), jnp.int32)
-        for k in range(min(self._n_rounds, n_pairs)):
+        n_rounds = min(self._n_rounds, n_pairs)
+        for k in range(n_rounds):
             choice = pref_s[:, k]
             active = valid_s[:, k] & ~placed
             cell = seg_s * n_pairs + choice
@@ -913,4 +932,5 @@ class PlacementPolicy(RoutingPolicy):
 
         return self._finalize_cross(s_s, home_s, valid_s[:, 0], pref_s[:, 0],
                                     placed, exec_pair, used, inv, state,
-                                    used_init, axis_name)
+                                    jnp.int32(n_rounds), used_init,
+                                    axis_name)

@@ -45,6 +45,7 @@ from functools import partial
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 import jax
 
@@ -55,6 +56,7 @@ from repro.serve.router import RequestBatch
 
 
 @partial(jax.jit, donate_argnums=(0, 5, 6, 7, 8))
+@jax.named_scope("settle")
 def _settle_carbon(w, infra, interference, net_slowdown, ci_table,
                    home, er, eh, tgt):
     """(N,) gCO2 of each committed (target, region, hour) at ACTUAL CI —
@@ -430,6 +432,9 @@ class QueueServeResult:
     routed_carbon_g: float  # non-shed rows only
     steps: tuple[QueueStep, ...]
     refits: int  # policy hot-swaps performed by the online refitter
+    #: (n_drafts,) admission rounds each draft's routing call ran, in draft
+    #: order (0 for policies without capacity admission)
+    admit_rounds: np.ndarray
 
     @property
     def shed_count(self) -> int:
@@ -473,130 +478,149 @@ def serve_stream(fr, batch: RequestBatch, region: np.ndarray,
     if plan is not None and pool is None:
         pool = WorkerPool(plan.n_regions,
                           slots_per_worker=plan.slots_per_server)
-    queue = RequestQueue.from_stream(batch, region, t_hours)
-    former = former or BatchFormer(mesh=getattr(fr, "mesh", None))
-    horizon = fr._horizon_h
-    n = len(queue)
-    if n and (queue.arr_hour.min() < 0 or queue.arr_hour.max() >= horizon):
-        raise ValueError(
-            f"t_hours must lie in [0, {horizon}) — the serve loop owns the "
-            f"time axis and never wraps")
+    with TraceAnnotation("gs.serve.setup"):
+        queue = RequestQueue.from_stream(batch, region, t_hours)
+        former = former or BatchFormer(mesh=getattr(fr, "mesh", None))
+        horizon = fr._horizon_h
+        n = len(queue)
+        if n and (queue.arr_hour.min() < 0
+                  or queue.arr_hour.max() >= horizon):
+            raise ValueError(
+                f"t_hours must lie in [0, {horizon}) — the serve loop owns "
+                f"the time axis and never wraps")
 
-    max_defer = int(getattr(fr.policy, "max_defer_h", 0))
-    W = getattr(fr.policy, "n_windows", None) or horizon
-    n_regions = fr.grid.n_regions
-    n_pairs = n_regions * N_TARGETS
-    routable = np.asarray(queue.batch.available).any(axis=1) if n else \
-        np.zeros(0, bool)
-    arr_hour = queue.arr_hour
-    deadline = queue.deadline(max_defer)
+        max_defer = int(getattr(fr.policy, "max_defer_h", 0))
+        W = getattr(fr.policy, "n_windows", None) or horizon
+        n_regions = fr.grid.n_regions
+        n_pairs = n_regions * N_TARGETS
+        routable = (np.asarray(queue.batch.available).any(axis=1) if n
+                    else np.zeros(0, bool))
+        arr_hour = queue.arr_hour
+        deadline = queue.deadline(max_defer)
 
-    tgt = np.zeros(n, np.int32)
-    er = queue.region.copy()
-    eh = arr_hour.copy()
-    shed = np.zeros(n, bool)
-    step_of = np.full(n, -1, np.int32)
-    used_committed = np.zeros(W * n_pairs, np.float32)
-    free_slots = np.full((n_regions, N_TARGETS), np.inf, np.float32)
+        tgt = np.zeros(n, np.int32)
+        er = queue.region.copy()
+        eh = arr_hour.copy()
+        shed = np.zeros(n, bool)
+        step_of = np.full(n, -1, np.int32)
+        used_committed = np.zeros(W * n_pairs, np.float32)
+        free_slots = np.full((n_regions, N_TARGETS), np.inf, np.float32)
 
-    steps: list[QueueStep] = []
+        steps: list[QueueStep] = []
+        rounds = []  # admission rounds per draft, on the device until the end
+        used0 = jnp.asarray(used_committed)
     for now in range(0, horizon, step_h):
-        last = now + step_h >= horizon
-        if pool is not None:
-            if plan is not None:
-                # retire last step's drains, then steer the pool toward the
-                # plan's counts for this hour; with the default one-step
-                # launch delay the tick below brings them online this step
-                pool.terminate_drained()
-                plan.apply_to_pool(pool, now)
-            pool.tick()
-            slots = pool.cap_matrix()
-            cap_scale = jnp.asarray(slots)
-        else:
-            slots, cap_scale = free_slots, None
+        with StepTraceAnnotation("gs.serve.step", step_num=now):
+            with TraceAnnotation("gs.serve.pool"):
+                if pool is not None:
+                    if plan is not None:
+                        # retire last step's drains, then steer the pool
+                        # toward the plan's counts for this hour; with the
+                        # default one-step launch delay the tick below
+                        # brings them online this step
+                        pool.terminate_drained()
+                        plan.apply_to_pool(pool, now)
+                    pool.tick()
+                    slots = pool.cap_matrix()
+                    cap_scale = jnp.asarray(slots)
+                else:
+                    slots, cap_scale = free_slots, None
 
-        ready = queue.ready(now + step_h, max_defer)
-        drafted = routed_k = shed_k = held_k = 0
-        n_batches = 0
-        for fb in former.draft(queue, ready, now, max_defer):
-            k = fb.n
-            drafted += k
-            n_batches += 1
-            res, state = fr._route_arrays(
-                fb.batch, fb.region, fb.hour,
-                cap_scale=cap_scale, used0=jnp.asarray(used_committed),
-                slack_np=fb.slack)
-            p_tgt = np.asarray(res.target)[:k]
-            p_shed_a = getattr(state, "shed", None)
-            p_shed = (np.zeros(k, bool) if p_shed_a is None
-                      else np.asarray(p_shed_a)[:k])
-            p_er_a = getattr(state, "exec_region", None)
-            p_er = (fb.region[:k] if p_er_a is None
-                    else np.asarray(p_er_a)[:k])
-            p_eh_a = getattr(state, "exec_hour", None)
-            temporal = p_eh_a is not None
-            p_eh = (fb.hour[:k] if not temporal
-                    else np.asarray(p_eh_a)[:k])
+            with TraceAnnotation("gs.serve.draft", step=now):
+                last = now + step_h >= horizon
+                ready = queue.ready(now + step_h, max_defer)
+                drafted = routed_k = shed_k = held_k = 0
+                n_batches = 0
+                drafts = former.draft(queue, ready, now, max_defer)
+            for j, fb in enumerate(drafts):
+                res, state = fr._route_arrays(
+                    fb.batch, fb.region, fb.hour,
+                    cap_scale=cap_scale, used0=used0, slack_np=fb.slack)
+                with TraceAnnotation("gs.serve.fetch", step=now, draft=j):
+                    k = fb.n
+                    p_tgt = np.asarray(res.target)[:k]
+                    p_shed_a = getattr(state, "shed", None)
+                    p_shed = (np.zeros(k, bool) if p_shed_a is None
+                              else np.asarray(p_shed_a)[:k])
+                    p_er_a = getattr(state, "exec_region", None)
+                    p_er = (fb.region[:k] if p_er_a is None
+                            else np.asarray(p_er_a)[:k])
+                    p_eh_a = getattr(state, "exec_hour", None)
+                    temporal = p_eh_a is not None
+                    p_eh = (fb.hour[:k] if not temporal
+                            else np.asarray(p_eh_a)[:k])
+                    rounds.append(getattr(state, "admit_rounds", None))
 
-            expired = deadline[fb.idx] < now + step_h
-            if temporal:
-                commit = (p_eh < now + step_h) | (p_shed & expired)
-            else:
-                commit = ~p_shed | expired
-            if last:
-                commit = np.ones(k, bool)
+                with TraceAnnotation("gs.serve.commit", step=now, draft=j):
+                    drafted += k
+                    n_batches += 1
+                    expired = deadline[fb.idx] < now + step_h
+                    if temporal:
+                        commit = (p_eh < now + step_h) | (p_shed & expired)
+                    else:
+                        commit = ~p_shed | expired
+                    if last:
+                        commit = np.ones(k, bool)
 
-            ci = fb.idx[commit]
-            c_shed = p_shed[commit]
-            queue.mark_routed(ci[~c_shed])
-            queue.mark_shed(ci[c_shed])
-            tgt[ci] = p_tgt[commit]
-            er[ci] = p_er[commit]
-            eh[ci] = p_eh[commit]
-            shed[ci] = c_shed
-            step_of[ci] = now
-            routed_k += int((~c_shed).sum())
-            shed_k += int(c_shed.sum())
-            held_k += int((~commit).sum())
+                    ci = fb.idx[commit]
+                    c_shed = p_shed[commit]
+                    queue.mark_routed(ci[~c_shed])
+                    queue.mark_shed(ci[c_shed])
+                    tgt[ci] = p_tgt[commit]
+                    er[ci] = p_er[commit]
+                    eh[ci] = p_eh[commit]
+                    shed[ci] = c_shed
+                    step_of[ci] = now
+                    routed_k += int((~c_shed).sum())
+                    shed_k += int(c_shed.sum())
+                    held_k += int((~commit).sum())
 
-            live = commit & ~p_shed & routable[fb.idx]
-            cells = ((p_eh[live] % W).astype(np.int64) * n_pairs
-                     + p_er[live] * N_TARGETS + p_tgt[live])
-            np.add.at(used_committed, cells, 1.0)
+                    live = commit & ~p_shed & routable[fb.idx]
+                    cells = ((p_eh[live] % W).astype(np.int64) * n_pairs
+                             + p_er[live] * N_TARGETS + p_tgt[live])
+                    np.add.at(used_committed, cells, 1.0)
+                    # the next draft admits against what this one committed
+                    used0 = jnp.asarray(used_committed)
 
-            if refitter is not None:
-                refitter.observe(fr, fb, p_tgt, commit & ~p_shed)
+                    if refitter is not None:
+                        refitter.observe(fr, fb, p_tgt, commit & ~p_shed)
 
-        refit = False
-        if refitter is not None:
-            fr, refit = refitter.step(fr)
-        steps.append(QueueStep(
-            now=now, drafted=drafted, n_batches=n_batches, routed=routed_k,
-            shed=shed_k, held=held_k, queued_after=queue.n_queued,
-            slots=slots, refit=refit))
+            with TraceAnnotation("gs.serve.refit", step=now):
+                refit = False
+                if refitter is not None:
+                    fr, refit = refitter.step(fr)
+                steps.append(QueueStep(
+                    now=now, drafted=drafted, n_batches=n_batches,
+                    routed=routed_k, shed=shed_k, held=held_k,
+                    queued_after=queue.n_queued, slots=slots, refit=refit))
 
     assert queue.n_queued == 0, "serve loop left requests unsettled"
 
     # ---- settle at actuals (same tail as the rolling re-planner) ---------
+    refits = 0 if refitter is None else refitter.n_refits
     if n == 0:
         return QueueServeResult(
             target=tgt, exec_region=er, exec_hour=eh,
             defer_hours=np.zeros(0, np.int32), shed=shed, step=step_of,
             carbon_g=np.zeros(0), total_carbon_g=0.0, routed_carbon_g=0.0,
-            steps=tuple(steps),
-            refits=0 if refitter is None else refitter.n_refits)
-    carbon = np.asarray(_settle_carbon(
-        queue.batch.workload(fr.cfg), fr.infra, fr._interference,
-        fr._net_slowdown, fr._ci_table, jnp.asarray(queue.region),
-        jnp.asarray(er), jnp.asarray(eh), jnp.asarray(tgt)))
+            steps=tuple(steps), refits=refits,
+            admit_rounds=np.zeros(0, np.int64))
+    with TraceAnnotation("gs.serve.settle"):
+        carbon = np.asarray(_settle_carbon(
+            queue.batch.workload(fr.cfg), fr.infra, fr._interference,
+            fr._net_slowdown, fr._ci_table, jnp.asarray(queue.region),
+            jnp.asarray(er), jnp.asarray(eh), jnp.asarray(tgt)))
+        # one read of every draft's counter, after the last step
+        admit_rounds = np.asarray(
+            [0 if r is None else int(r) for r in jax.device_get(rounds)],
+            np.int64)
     defer = np.where(shed, 0, eh - arr_hour).astype(np.int32)
     return QueueServeResult(
         target=tgt, exec_region=er, exec_hour=eh, defer_hours=defer,
         shed=shed, step=step_of, carbon_g=carbon,
         total_carbon_g=float(carbon.sum()),
         routed_carbon_g=float(carbon[~shed].sum()),
-        steps=tuple(steps),
-        refits=0 if refitter is None else refitter.n_refits)
+        steps=tuple(steps), refits=refits, admit_rounds=admit_rounds)
 
 
 def admit_batches(result: QueueServeResult, engine) -> list[np.ndarray]:

@@ -36,6 +36,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core import carbon_model
@@ -511,113 +512,115 @@ class FleetRouter:
                 order=order, inv_order=inv_order, slack=slack,
                 factors=factors, fc_table=ci_fc, cap_scale=cap_scale,
                 used0=used0)
-            shed = getattr(new_state, "shed", None)
-            exec_region = getattr(new_state, "exec_region", None)
-            exec_hour = getattr(new_state, "exec_hour", None)
-            take = lambda o, t: jnp.take_along_axis(
-                o.total_cf, t[:, None], axis=1)[:, 0]
-            take2 = lambda a, t: jnp.take_along_axis(
-                a, t[:, None], axis=1)[:, 0]
-            if exec_region is None and exec_hour is None:
-                # no cross-region / deferred placement: execute on arrival,
-                # charged at the arrival cell's ACTUAL CI
-                exec_region = region
-                spilled = jnp.zeros((), jnp.int32)
-                carbon = take_act(targets)
-                feas = take2(out.ok, targets)
-            elif factors is not None:
-                # executed-placement accounting on the factorized evaluator:
-                # CI rows gathered at the EXECUTING (region, hour) — home
-                # [mobile, edge_net] components stay billed in the home
-                # region at the execution hour (the device draws energy when
-                # the work actually runs), the WAN hop enters the QoS check
-                # — and the precomputed factors turn them into carbon with
-                # one einsum instead of the out_exec Table-1 re-evaluation.
-                er = region if exec_region is None else exec_region
-                eh = hour if exec_hour is None else exec_hour
-                exec_region = er
-                ci_exec = jnp.concatenate(
-                    [ci_table[region, eh][:, :2],
-                     ci_table[er, eh][:, 2:]], axis=1)
-                cf_exec = carbon_model.total_cf_from_factors(factors, ci_exec)
-                ok_exec = carbon_model.qos_feasible_from_factors(
-                    factors, w, rtt_s[region, er]) & avail
-                carbon = take2(cf_exec, targets)
-                feas = take2(ok_exec, targets)
-                moved = er != region
+            # executed-placement accounting and the call's aggregates
+            with jax.named_scope("account"):
+                shed = getattr(new_state, "shed", None)
+                exec_region = getattr(new_state, "exec_region", None)
+                exec_hour = getattr(new_state, "exec_hour", None)
+                take = lambda o, t: jnp.take_along_axis(
+                    o.total_cf, t[:, None], axis=1)[:, 0]
+                take2 = lambda a, t: jnp.take_along_axis(
+                    a, t[:, None], axis=1)[:, 0]
+                if exec_region is None and exec_hour is None:
+                    # no cross-region / deferred placement: execute on arrival,
+                    # charged at the arrival cell's ACTUAL CI
+                    exec_region = region
+                    spilled = jnp.zeros((), jnp.int32)
+                    carbon = take_act(targets)
+                    feas = take2(out.ok, targets)
+                elif factors is not None:
+                    # executed-placement accounting on the factorized evaluator:
+                    # CI rows gathered at the EXECUTING (region, hour) — home
+                    # [mobile, edge_net] components stay billed in the home
+                    # region at the execution hour (the device draws energy when
+                    # the work actually runs), the WAN hop enters the QoS check
+                    # — and the precomputed factors turn them into carbon with
+                    # one einsum instead of the out_exec Table-1 re-evaluation.
+                    er = region if exec_region is None else exec_region
+                    eh = hour if exec_hour is None else exec_hour
+                    exec_region = er
+                    ci_exec = jnp.concatenate(
+                        [ci_table[region, eh][:, :2],
+                         ci_table[er, eh][:, 2:]], axis=1)
+                    cf_exec = carbon_model.total_cf_from_factors(factors, ci_exec)
+                    ok_exec = carbon_model.qos_feasible_from_factors(
+                        factors, w, rtt_s[region, er]) & avail
+                    carbon = take2(cf_exec, targets)
+                    feas = take2(ok_exec, targets)
+                    moved = er != region
+                    if shed is not None:
+                        moved = moved & ~shed
+                    spilled = moved.sum().astype(jnp.int32)
+                else:
+                    # legacy sweep path (non-factorizable inner policies):
+                    # carbon/QoS accounting under the EXECUTING region's CI for
+                    # rows that moved; unmoved rows keep the home-region values
+                    # bit-for-bit (adjacency == I parity with tier-only spill).
+                    # Only the infrastructure relocates: the device and access
+                    # network still draw energy in the HOME region, so the
+                    # executing env mixes home [mobile, edge_net] CI with the
+                    # executing region's [edge_dc, core_net, hyper_dc] — the
+                    # same mixing PlacementPolicy.pair_scores decides with.
+                    # Home components come from the ACTUAL table (== env.ci
+                    # without a forecast — the historical values bit-for-bit).
+                    ci_exec = jnp.concatenate(
+                        [ci_table[region, hour][:, :2],
+                         ci_table[exec_region, hour][:, 2:]],
+                        axis=1)
+                    env_exec = Environment(ci=ci_exec,
+                                           interference=interference,
+                                           net_slowdown=net_slowdown)
+                    out_exec = carbon_model.route_many_envs(w, infra, env_exec,
+                                                            avail)
+                    moved = exec_region != region
+                    if shed is not None:
+                        moved = moved & ~shed
+                    spilled = moved.sum().astype(jnp.int32)
+                    carbon = jnp.where(moved, take(out_exec, targets),
+                                       take_act(targets))
+                    feas = jnp.where(moved, take2(out_exec.ok, targets),
+                                     take2(out.ok, targets))
+                # (region, tier) assignment counts as a one-hot reduction over
+                # the flattened pair index — a dense sum, not an N-wide scatter
+                pair = exec_region * N_TARGETS + targets
+                one_hot = jax.nn.one_hot(pair, n_regions * N_TARGETS,
+                                         dtype=jnp.int32)
                 if shed is not None:
-                    moved = moved & ~shed
-                spilled = moved.sum().astype(jnp.int32)
-            else:
-                # legacy sweep path (non-factorizable inner policies):
-                # carbon/QoS accounting under the EXECUTING region's CI for
-                # rows that moved; unmoved rows keep the home-region values
-                # bit-for-bit (adjacency == I parity with tier-only spill).
-                # Only the infrastructure relocates: the device and access
-                # network still draw energy in the HOME region, so the
-                # executing env mixes home [mobile, edge_net] CI with the
-                # executing region's [edge_dc, core_net, hyper_dc] — the
-                # same mixing PlacementPolicy.pair_scores decides with.
-                # Home components come from the ACTUAL table (== env.ci
-                # without a forecast — the historical values bit-for-bit).
-                ci_exec = jnp.concatenate(
-                    [ci_table[region, hour][:, :2],
-                     ci_table[exec_region, hour][:, 2:]],
-                    axis=1)
-                env_exec = Environment(ci=ci_exec,
-                                       interference=interference,
-                                       net_slowdown=net_slowdown)
-                out_exec = carbon_model.route_many_envs(w, infra, env_exec,
-                                                        avail)
-                moved = exec_region != region
-                if shed is not None:
-                    moved = moved & ~shed
-                spilled = moved.sum().astype(jnp.int32)
-                carbon = jnp.where(moved, take(out_exec, targets),
-                                   take_act(targets))
-                feas = jnp.where(moved, take2(out_exec.ok, targets),
-                                 take2(out.ok, targets))
-            # (region, tier) assignment counts as a one-hot reduction over
-            # the flattened pair index — a dense sum, not an N-wide scatter
-            pair = exec_region * N_TARGETS + targets
-            one_hot = jax.nn.one_hot(pair, n_regions * N_TARGETS,
-                                     dtype=jnp.int32)
-            if shed is not None:
-                one_hot = one_hot * (~shed)[:, None].astype(jnp.int32)
-            counts = one_hot.sum(axis=0).reshape(n_regions, N_TARGETS)
-            defer = getattr(new_state, "defer_hours", None)
-            if defer is None:
-                deferred = jnp.zeros((), jnp.int32)
-                mean_defer = jnp.zeros((), jnp.float32)
-            else:
-                dmask = defer > 0
-                if shed is not None:
-                    dmask = dmask & ~shed
-                deferred = dmask.sum().astype(jnp.int32)
-                mean_defer = ((defer * dmask).sum()
-                              / jnp.maximum(deferred, 1)).astype(jnp.float32)
-            return FleetRouteResult(
-                target=targets,
-                carbon_g=carbon,
-                feasible=feas,
-                counts=counts,
-                total_carbon_g=carbon.sum(),
-                routed_carbon_g=(carbon.sum() if shed is None
-                                 else (carbon * ~shed).sum()),
-                # reference baselines decide on the forecast view too (they
-                # are schedulers, not oracles-with-hindsight), but are
-                # charged at actuals like everything else
-                latency_opt_carbon_g=take_act(out.target_latency).sum(),
-                energy_opt_carbon_g=take_act(out.target_energy).sum(),
-                oracle_carbon_g=take_act(out.target).sum(),
-                infeasible_count=(~feas).sum().astype(jnp.int32),
-                shed_count=(jnp.zeros((), jnp.int32) if shed is None
-                            else shed.sum().astype(jnp.int32)),
-                exec_region=exec_region,
-                spilled_count=spilled,
-                deferred_count=deferred,
-                mean_defer_hours=mean_defer,
-            ), new_state
+                    one_hot = one_hot * (~shed)[:, None].astype(jnp.int32)
+                counts = one_hot.sum(axis=0).reshape(n_regions, N_TARGETS)
+                defer = getattr(new_state, "defer_hours", None)
+                if defer is None:
+                    deferred = jnp.zeros((), jnp.int32)
+                    mean_defer = jnp.zeros((), jnp.float32)
+                else:
+                    dmask = defer > 0
+                    if shed is not None:
+                        dmask = dmask & ~shed
+                    deferred = dmask.sum().astype(jnp.int32)
+                    mean_defer = ((defer * dmask).sum()
+                                  / jnp.maximum(deferred, 1)).astype(jnp.float32)
+                return FleetRouteResult(
+                    target=targets,
+                    carbon_g=carbon,
+                    feasible=feas,
+                    counts=counts,
+                    total_carbon_g=carbon.sum(),
+                    routed_carbon_g=(carbon.sum() if shed is None
+                                     else (carbon * ~shed).sum()),
+                    # reference baselines decide on the forecast view too (they
+                    # are schedulers, not oracles-with-hindsight), but are
+                    # charged at actuals like everything else
+                    latency_opt_carbon_g=take_act(out.target_latency).sum(),
+                    energy_opt_carbon_g=take_act(out.target_energy).sum(),
+                    oracle_carbon_g=take_act(out.target).sum(),
+                    infeasible_count=(~feas).sum().astype(jnp.int32),
+                    shed_count=(jnp.zeros((), jnp.int32) if shed is None
+                                else shed.sum().astype(jnp.int32)),
+                    exec_region=exec_region,
+                    spilled_count=spilled,
+                    deferred_count=deferred,
+                    mean_defer_hours=mean_defer,
+                ), new_state
 
         self._fleet_route = _fleet_route
 
@@ -656,9 +659,10 @@ class FleetRouter:
     ) -> tuple[FleetRouteResult, object]:
         """``route_stream`` + the policy's final state (e.g. the
         ``PlacementState`` counters/shed mask of a ``PlacementPolicy``)."""
-        hour_np = (np.floor(np.asarray(t_hours))
-                   % self._horizon_h).astype(np.int32)
-        region_np = np.asarray(region).astype(np.int32)
+        with TraceAnnotation("gs.route.prepare"):
+            hour_np = (np.floor(np.asarray(t_hours))
+                       % self._horizon_h).astype(np.int32)
+            region_np = np.asarray(region).astype(np.int32)
         return self._route_arrays(batch, region_np, hour_np, mesh=mesh)
 
     def _route_arrays(self, batch: RequestBatch, region_np: np.ndarray,
@@ -678,16 +682,24 @@ class FleetRouter:
         ``mesh`` field) the call delegates to the device-sharded program
         (``repro.serve.distributed``) — which is why every caller of this
         seam (``serve_stream``, the rolling re-planner) rides the sharded
-        path automatically."""
+        path automatically.
+
+        Host spans on the profiler's clock: ``gs.route.prepare`` (order
+        sort, inverse, uploads, initial state) and ``gs.route.dispatch``
+        (enqueue of the jitted call; a long one is a compile or a hidden
+        sync)."""
         mesh = self.mesh if mesh is None else mesh
         if mesh is not None and len(batch) > 0:
             from repro.serve import distributed
             return distributed.route_arrays_sharded(
                 self, batch, region_np, hour_np, mesh, ci_fc=ci_fc,
                 cap_scale=cap_scale, used0=used0, slack_np=slack_np)
-        return self._fleet_route(*self._route_args(
-            batch, region_np, hour_np, ci_fc=ci_fc, cap_scale=cap_scale,
-            used0=used0, slack_np=slack_np))
+        with TraceAnnotation("gs.route.prepare"):
+            args = self._route_args(batch, region_np, hour_np, ci_fc=ci_fc,
+                                    cap_scale=cap_scale, used0=used0,
+                                    slack_np=slack_np)
+        with TraceAnnotation("gs.route.dispatch"):
+            return self._fleet_route(*args)
 
     def _route_args(self, batch: RequestBatch, region_np: np.ndarray,
                     hour_np: np.ndarray, *, ci_fc: jax.Array | None = None,

@@ -83,6 +83,9 @@ class TemporalState:
                     hour's CI.
     ``defer_hours`` (N,) int32 — hours deferred past arrival; always within
                     ``[0, slack]`` (property-tested).
+    ``admit_rounds`` () int32 — admission rounds run (the ``while_loop``'s
+                    final trip count), summed over decisions like
+                    ``counts``.
     """
 
     counts: jax.Array
@@ -91,6 +94,7 @@ class TemporalState:
     shed_pair: jax.Array
     exec_hour: jax.Array
     defer_hours: jax.Array
+    admit_rounds: jax.Array
 
 
 @dataclasses.dataclass
@@ -183,8 +187,10 @@ class TemporalPolicy(PlacementPolicy):
             exec_region=jnp.zeros((n_requests,), jnp.int32),
             shed_pair=base.shed_pair,
             exec_hour=jnp.zeros((n_requests,), jnp.int32),
-            defer_hours=jnp.zeros((n_requests,), jnp.int32))
+            defer_hours=jnp.zeros((n_requests,), jnp.int32),
+            admit_rounds=base.admit_rounds)
 
+    @jax.named_scope("score")
     def candidate_scores(self, factors, w, env, avail, home: jax.Array,
                          hr: jax.Array,
                          fc_table: jax.Array | None = None) -> jax.Array:
@@ -314,6 +320,19 @@ class TemporalPolicy(PlacementPolicy):
             sub_p = self._cand_pair.shape[1] if sparse else n_pairs
             s_all = jnp.where(d_ok[:, :, None, None], s_all, jnp.inf)
         s = jnp.moveaxis(s_all, 0, 1).reshape(n, (S + 1) * sub_p)
+        return self._admit(s, sub_p, win, home, hr, order, inv_order, state,
+                           cap_scale, used0, axis_name)
+
+    @jax.named_scope("admit")
+    def _admit(self, s, sub_p, win, home, hr, order, inv_order, state,
+               cap_scale, used0, axis_name):
+        """Joint (defer, region, tier) admission of the (N, (S+1)·sub_p)
+        candidate scores ``s``: skip-full best-open attempts under a
+        ``lax.while_loop``, then the shed/fallback tail."""
+        n = s.shape[0]
+        n_regions, n_pairs = self._caps.shape[0], self._caps.size
+        W, S = self.n_windows, self.max_defer_h
+        sparse = getattr(self, "_sparse", False)
         width = (S + 1) * sub_p
 
         # --- to segment-sorted stream order -------------------------------
@@ -455,7 +474,7 @@ class TemporalPolicy(PlacementPolicy):
                      else jnp.asarray(used0, jnp.float32).reshape(-1))
         placed0 = jnp.zeros((n,), bool)
         mask0 = open_mask(used_init, placed0)
-        _, _, used, placed, exec_pair, exec_d, _ = jax.lax.while_loop(
+        _, _, used, placed, exec_pair, exec_d, rounds = jax.lax.while_loop(
             cond, body,
             (_global_any(mask0.any(), axis_name), mask0, used_init, placed0,
              jnp.zeros((n,), jnp.int32),
@@ -507,4 +526,5 @@ class TemporalPolicy(PlacementPolicy):
             exec_region=exec_region,
             shed_pair=state.shed_pair + shed_pair,
             exec_hour=exec_hour,
-            defer_hours=defer)
+            defer_hours=defer,
+            admit_rounds=state.admit_rounds + rounds)
