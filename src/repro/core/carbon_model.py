@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from repro.core.constants import (
     J_PER_KWH,
     N_COMPONENTS,
-    N_TARGETS,
     Component,
     Target,
 )
@@ -142,63 +141,66 @@ def evaluate(w: Workload, infra: InfraParams, env: Environment) -> CFBreakdown:
     p_comp = infra.p_comp
     p_idle = infra.p_idle
 
-    op = jnp.zeros((N_TARGETS, N_COMPONENTS), jnp.float32)
-    emb = jnp.zeros((N_TARGETS, N_COMPONENTS), jnp.float32)
-
     M, EN, ED, CN, HD = (Component.MOBILE, Component.EDGE_NETWORK,
                          Component.EDGE_DC, Component.CORE_NETWORK,
                          Component.HYPERSCALE_DC)
-    MOB, EDC, HYP = Target.MOBILE, Target.EDGE_DC, Target.HYPERSCALE_DC
+    zero = jnp.zeros((), jnp.float32)  # Table 1 '-': component not involved
+
+    # The table is built whole, one stack per target row in component order:
+    # under vmap a per-entry ``.at[t, c].set`` write becomes one
+    # dynamic-update-slice over the whole batched (N, 3, 5) array.
 
     # ---- Target: Mobile Device (Table 1, first block) ------------------------
-    op = op.at[MOB, M].set(_cf(t_m * p_comp[0], ci[M]))
-    op = op.at[MOB, ED].set(_cf(t_m * p_idle[1] / infra.n_user_edge, ci[ED]))
-    op = op.at[MOB, HD].set(_cf(t_m * p_idle[2] / infra.n_user_dc, ci[HD]))
-    emb = emb.at[MOB, M].set(infra.ecf_g[0] * t_m / infra.lifetime_s[0])
-    emb = emb.at[MOB, ED].set(
-        infra.ecf_g[1] / infra.n_user_edge * t_m / infra.lifetime_s[1])
-    emb = emb.at[MOB, HD].set(
-        infra.ecf_g[2] / infra.n_user_dc * t_m / infra.lifetime_s[2])
+    op_mob = jnp.stack([
+        _cf(t_m * p_comp[0], ci[M]),
+        zero,
+        _cf(t_m * p_idle[1] / infra.n_user_edge, ci[ED]),
+        zero,
+        _cf(t_m * p_idle[2] / infra.n_user_dc, ci[HD]),
+    ])
+    emb_mob = jnp.stack([
+        infra.ecf_g[0] * t_m / infra.lifetime_s[0],
+        zero,
+        infra.ecf_g[1] / infra.n_user_edge * t_m / infra.lifetime_s[1],
+        zero,
+        infra.ecf_g[2] / infra.n_user_dc * t_m / infra.lifetime_s[2],
+    ])
 
     # ---- Target: Edge DC (Table 1, second block) ------------------------------
-    op = op.at[EDC, M].set(
-        _cf(t_ce_e * infra.p_comm_mobile + t_e * p_idle[0], ci[M]))
-    op = op.at[EDC, EN].set(
-        _cf(t_ce_e * infra.net_p[0] / infra.net_n_user[0], ci[EN]))
-    op = op.at[EDC, ED].set(
-        _cf(t_e * p_comp[1] / infra.n_user_edge, ci[ED]))
-    op = op.at[EDC, HD].set(
-        _cf((t_ce + t_e) * p_idle[2] / infra.n_user_dc, ci[HD]))
-    emb = emb.at[EDC, M].set(infra.ecf_g[0] * (t_ce + t_e) / infra.lifetime_s[0])
-    emb = emb.at[EDC, EN].set(
-        infra.net_ecf_g[0] / infra.net_n_user[0] * t_ce / infra.net_lifetime_s[0])
-    emb = emb.at[EDC, ED].set(
-        infra.ecf_g[1] / infra.n_user_edge * t_e / infra.lifetime_s[1])
-    emb = emb.at[EDC, HD].set(
-        infra.ecf_g[2] / infra.n_user_dc * (t_ce + t_e) / infra.lifetime_s[2])
+    op_edc = jnp.stack([
+        _cf(t_ce_e * infra.p_comm_mobile + t_e * p_idle[0], ci[M]),
+        _cf(t_ce_e * infra.net_p[0] / infra.net_n_user[0], ci[EN]),
+        _cf(t_e * p_comp[1] / infra.n_user_edge, ci[ED]),
+        zero,
+        _cf((t_ce + t_e) * p_idle[2] / infra.n_user_dc, ci[HD]),
+    ])
+    emb_edc = jnp.stack([
+        infra.ecf_g[0] * (t_ce + t_e) / infra.lifetime_s[0],
+        infra.net_ecf_g[0] / infra.net_n_user[0] * t_ce / infra.net_lifetime_s[0],
+        infra.ecf_g[1] / infra.n_user_edge * t_e / infra.lifetime_s[1],
+        zero,
+        infra.ecf_g[2] / infra.n_user_dc * (t_ce + t_e) / infra.lifetime_s[2],
+    ])
 
     # ---- Target: Hyperscale DC (Table 1, third block) -------------------------
-    op = op.at[HYP, M].set(
-        _cf(t_ce_e * infra.p_comm_mobile + (t_cr + t_h) * p_idle[0], ci[M]))
-    op = op.at[HYP, EN].set(
-        _cf(t_ce_e * infra.net_p[0] / infra.net_n_user[0], ci[EN]))
-    op = op.at[HYP, ED].set(
-        _cf((t_ce + t_cr + t_h) * p_idle[1] / infra.n_user_edge, ci[ED]))
-    op = op.at[HYP, CN].set(
-        _cf(t_cr_e * infra.net_p[1] / infra.net_n_user[1], ci[CN]))
-    op = op.at[HYP, HD].set(
-        _cf(t_h * p_comp[2] / infra.n_batch_dc, ci[HD]))
-    emb = emb.at[HYP, M].set(
-        infra.ecf_g[0] * (t_ce + t_cr + t_h) / infra.lifetime_s[0])
-    emb = emb.at[HYP, EN].set(
-        infra.net_ecf_g[0] / infra.net_n_user[0] * t_ce / infra.net_lifetime_s[0])
-    emb = emb.at[HYP, ED].set(
+    op_hyp = jnp.stack([
+        _cf(t_ce_e * infra.p_comm_mobile + (t_cr + t_h) * p_idle[0], ci[M]),
+        _cf(t_ce_e * infra.net_p[0] / infra.net_n_user[0], ci[EN]),
+        _cf((t_ce + t_cr + t_h) * p_idle[1] / infra.n_user_edge, ci[ED]),
+        _cf(t_cr_e * infra.net_p[1] / infra.net_n_user[1], ci[CN]),
+        _cf(t_h * p_comp[2] / infra.n_batch_dc, ci[HD]),
+    ])
+    emb_hyp = jnp.stack([
+        infra.ecf_g[0] * (t_ce + t_cr + t_h) / infra.lifetime_s[0],
+        infra.net_ecf_g[0] / infra.net_n_user[0] * t_ce / infra.net_lifetime_s[0],
         infra.ecf_g[1] / infra.n_user_edge * (t_ce + t_cr + t_h)
-        / infra.lifetime_s[1])
-    emb = emb.at[HYP, CN].set(
-        infra.net_ecf_g[1] / infra.net_n_user[1] * t_cr / infra.net_lifetime_s[1])
-    emb = emb.at[HYP, HD].set(
-        infra.ecf_g[2] / infra.n_batch_dc * t_h / infra.lifetime_s[2])
+        / infra.lifetime_s[1],
+        infra.net_ecf_g[1] / infra.net_n_user[1] * t_cr / infra.net_lifetime_s[1],
+        infra.ecf_g[2] / infra.n_batch_dc * t_h / infra.lifetime_s[2],
+    ])
+
+    op = jnp.stack([op_mob, op_edc, op_hyp])
+    emb = jnp.stack([emb_mob, emb_edc, emb_hyp])
 
     latency = jnp.stack([t_m, t_ce + t_e, t_ce + t_cr + t_h])
     return CFBreakdown(op_cf=op, emb_cf=emb, latency=latency,

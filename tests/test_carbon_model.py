@@ -5,6 +5,7 @@ import hypothesis.strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import (
     Environment,
@@ -264,3 +265,74 @@ class TestFactorizedEvaluator:
             np.asarray(base))
         hop = carbon_model.qos_feasible(b.latency, b.t_comm, w, 1e9)
         assert not bool(np.asarray(hop).any())
+
+
+class TestBatchedBuild:
+    """The Table-1 arrays are built whole: under ``vmap`` a per-entry
+    ``.at[t, c].set`` write would become one dynamic-update-slice over the
+    whole batched (N, 3, 5) array, a full pass over it per entry."""
+
+    @staticmethod
+    def _rows(n, seed=11):
+        rng = np.random.default_rng(seed)
+        from repro.core.workloads import batch_workloads
+
+        stream = rng.random(n) < 0.25  # cloud-gaming style streams too
+        return batch_workloads(
+            flops=rng.uniform(1e8, 1e13, n),
+            mem_bytes=rng.uniform(1e6, 1e10, n),
+            data_in=rng.uniform(1e3, 1e7, n),
+            data_out=rng.uniform(1e3, 1e6, n),
+            latency_req=rng.choice([0.05, 0.5, 2.0, 30.0], n),
+            continuous=stream.astype(np.float32),
+            fps_req=np.where(stream, rng.choice([30.0, 60.0], n), 0.0),
+            mobile_eff_scale=rng.uniform(0.5, 2.0, n),
+        )
+
+    @pytest.mark.parametrize("entry", ["energy_factors_batch",
+                                       "evaluate_batch"])
+    def test_batched_build_has_no_per_entry_writes(self, entry):
+        w = self._rows(4096)
+        interference = jnp.asarray([1.1, 1.0, 1.3], jnp.float32)
+        net_slowdown = jnp.asarray([1.2, 1.0], jnp.float32)
+        if entry == "energy_factors_batch":
+            args = (w, INFRA, interference, net_slowdown)
+        else:
+            args = (w, INFRA, Environment.make(300.0, 350.0, 280.0, 320.0,
+                                               interference, net_slowdown))
+        hlo = jax.jit(getattr(carbon_model, entry)).lower(*args).compile()
+        text = hlo.as_text()
+        assert "dynamic-update-slice" not in text
+        assert "scatter" not in text
+
+    @pytest.mark.parametrize("fleet", [tpu_fleet, paper_fleet],
+                             ids=["tpu_fleet", "paper_fleet"])
+    def test_batched_matches_row_by_row(self, fleet):
+        """``vmap(evaluate)`` against ``evaluate`` row by row, both op by op.
+        The jitted batch is held to the Table-1 arrays' tolerance only: the
+        compiler contracts multiply-adds in fused loops (about 1 ulp)."""
+        n = 256
+        w = self._rows(n, seed=5)
+        infra = pack_infra(fleet(), "act")
+        env = Environment.make(300.0, 350.0, 280.0, 320.0,
+                               interference=(1.1, 1.0, 1.3),
+                               net_slowdown=(1.2, 1.4))
+        batched = jax.vmap(evaluate, in_axes=(0, None, None))
+        got = batched(w, infra, env)
+        fused = jax.jit(batched)(w, infra, env)
+        rows = [evaluate(jax.tree.map(lambda a, i=i: a[i], w), infra, env)
+                for i in range(n)]
+        ref = jax.tree.map(lambda *xs: np.stack(xs), *rows)
+        M, E = Target.MOBILE, Target.EDGE_DC
+        EN, CN = 1, 3  # Component.EDGE_NETWORK, CORE_NETWORK
+        for out in (got, fused):
+            for name in ("op_cf", "emb_cf"):
+                b = np.asarray(getattr(out, name))
+                assert b.shape == (n, 3, 5) and b.dtype == np.float32
+                np.testing.assert_allclose(b, getattr(ref, name), rtol=1e-6,
+                                           err_msg=name)
+                assert (b[:, M, EN] == 0).all() and (b[:, M, CN] == 0).all()
+                assert (b[:, E, CN] == 0).all()
+        for name in ("latency", "t_comp", "t_comm"):
+            np.testing.assert_array_equal(np.asarray(getattr(got, name)),
+                                          getattr(ref, name), err_msg=name)
