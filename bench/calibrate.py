@@ -28,7 +28,7 @@ sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(BENCH.parent / "src"))
 
 import run  # noqa: E402
-from harness import cells, check, grids, traffic  # noqa: E402
+from harness import cells, check, traffic  # noqa: E402
 
 
 def as_output(dec) -> dict:
@@ -45,18 +45,19 @@ def calibrate(cell, seeds, control_seeds):
 
     os.environ["JAX_COMPILATION_CACHE_DIR"] = str(run.COMPILE_CACHE)
     enable_compile_cache()
-    g = grids.build(cell.config["grid"], cell.config["source_ci"])
+    g = cell.grid()
     n_regions = g["ci_hourly"].shape[0]
-    caps = run.cell_caps(cell, n_regions)
+    entry_cls = cell.entry()
+    caps = entry_cls.caps(cell, n_regions)
+    ref = cell.reference()
     tr = cell.traffic
     for seed in sorted(set(seeds) | set(control_seeds)):
         streams = [traffic.generate(tr, n_regions,
                                     traffic.stream_rng(seed, k))
                    for k in range(int(tr["streams"]))]
-        probs = [run.reference_problem(cell, g, caps, s) for s in streams]
+        probs = [ref.problem(cell, g, caps, s) for s in streams]
         if seed in seeds:
-            entry = program.ENTRIES[tr["entry"]](
-                cell.config, tr, g, caps, streams, program.Spans(False))
+            entry = entry_cls(cell, g, caps, streams, program.Spans(False))
             outs = [entry.once(k)[1] for k in range(len(streams))]
             del entry
             yield dict(seed=seed, side="program", **check.worst(
@@ -66,7 +67,7 @@ def calibrate(cell, seeds, control_seeds):
                               & ~o["shed"]).sum())
                          for o, s in zip(outs, streams)])
         if seed in control_seeds:
-            ctrl = [as_output(run.reference_problem(
+            ctrl = [as_output(ref.problem(
                 cell, g, caps, s, precision="high").solve())
                 for s in streams]
             yield dict(seed=seed, side="control", **check.worst(

@@ -1,11 +1,26 @@
 """Finding a cell's pieces by name: its entry in ``BENCHMARK.json``, the
-configuration and traffic files it names, its limits, and the metrics it
-reports. Adding a cell, configuration, mix or per-layer metric is adding
-files; nothing here lists them."""
+configuration and traffic files it names, its limits, the metrics it
+reports, and the code of each part of its deployment. Adding a cell,
+configuration, mix, per-layer metric, entry point, policy, reference or
+grid kind is adding files; nothing here lists them.
+
+A deployment's code is found under the cell's bench directory by the name
+its files give:
+
+``entries/<traffic["entry"]>.py``    class ``Entry``: the timed entry point
+``policies/<config["policy_kind"]>.py``  ``build(cfg, fleet, g, caps)``: the
+                                     program's routing policy
+``references/<config["policy_kind"]>.py``  ``problem(cell, g, caps, stream,
+                                     precision)``: the plain reference
+``grids/<config["grid"]["kind"]>.py``  ``build(spec, source_ci)``: the grid
+                                     tables
+``metrics/<metric>.py``              ``read(observed)``: a per-layer metric
+"""
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import math
@@ -24,7 +39,29 @@ class Cell:
     limits: dict
     end_to_end: list  # BENCHMARK.json metric entries this cell reports
     per_layer: list
-    bench_dir: Path = BENCH  # where its metric readers live
+    bench_dir: Path = BENCH  # where its code and metric readers live
+
+    def part(self, kind: str, name: str):
+        """The module ``<bench_dir>/<kind>/<name>.py``."""
+        return module(self.bench_dir / kind / f"{name}.py")
+
+    def entry(self):
+        """The class of the entry point the cell's traffic drives."""
+        return self.part("entries", self.traffic["entry"]).Entry
+
+    def policy(self):
+        """The module that builds the program's policy."""
+        return self.part("policies", self.config["policy_kind"])
+
+    def reference(self):
+        """The module of the plain reference of the cell's policy."""
+        return self.part("references", self.config["policy_kind"])
+
+    def grid(self) -> dict:
+        """The deployment's grid tables (``harness.grids``)."""
+        spec = self.config["grid"]
+        return self.part("grids", spec["kind"]).build(
+            spec, self.config["source_ci"])
 
 
 def _json(path: Path) -> dict:
@@ -78,11 +115,17 @@ def slots_per_worker(config: dict, requests: int, n_regions: int) -> int:
     return max(1, dc_capacity(config, requests, n_regions) // workers)
 
 
-def metric_reader(name: str, bench_dir: Path = BENCH):
-    """The ``read(observed)`` function of ``bench/metrics/<name>.py``."""
-    path = bench_dir / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
+@functools.cache
+def module(path: Path):
+    """The Python file at ``path``, loaded once per process."""
+    rel = path.with_suffix("").parts[-2:]
+    name = "bench_" + "_".join(rel).replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, bench_dir: Path = BENCH):
+    """The ``read(observed)`` function of ``bench/metrics/<name>.py``."""
+    return module(bench_dir / "metrics" / f"{name}.py").read

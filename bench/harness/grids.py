@@ -3,7 +3,10 @@
 The benchmark builds each deployment's grid tables itself, from the
 configuration file, and hands the same arrays both to the program (through
 the public ``CarbonGrid`` constructor) and to the plain reference, so the
-reference takes no table the program has made. The regional generation
+reference takes no table the program has made. Each grid kind is a file of
+its own, ``bench/grids/<kind>.py`` (``cells.Cell.grid``); this module holds
+what they and the references share: the regional generation profiles, the
+per-region tables and the Table-1 component view. The regional generation
 mixes follow the program's own synthesis in ``repro.core.carbon_intensity``
 (``grid_trace``, ``CarbonGrid.fully_connected``);
 ``bench/tests/test_grids.py`` pins that they describe the same deployment.
@@ -84,37 +87,15 @@ def profile_ci(name: str, source_ci) -> np.ndarray:
     return PROFILES[name](h) @ np.asarray(source_ci, np.float64)
 
 
-def _dense_tables(ci: np.ndarray, mobile: np.ndarray, core: np.ndarray,
-                  pue: float) -> dict:
+def dense_tables(ci: np.ndarray, mobile: np.ndarray, core: np.ndarray,
+                 pue: float) -> dict:
+    """The per-region tables of a grid: hourly CI ``ci`` (R, 24), battery
+    and core-path CI (R,), one PUE for every region and hour."""
     r = len(ci)
     return dict(ci_hourly=ci.astype(np.float32),
                 ci_mobile=mobile.astype(np.float32),
                 ci_core=core.astype(np.float32),
                 pue=np.full((r, HOURS), pue, np.float32))
-
-
-def regions_grid(spec: dict, source_ci) -> dict:
-    """Fully connected regional grid: each region's CI from its profile,
-    battery CI at the uniform (all-day) charging average, core CI at the
-    daily mean, one latency penalty on every remote hop."""
-    ci = np.stack([profile_ci(p, source_ci) for p in spec["regions"]])
-    mean = ci.mean(axis=1)
-    out = _dense_tables(ci, mean, mean, float(spec["pue"]))
-    r = len(ci)
-    pen = np.full((r, r), spec["latency_penalty"], np.float32)
-    np.fill_diagonal(pen, 1.0)
-    rtt = np.full((r, r), spec["rtt_s"], np.float32)
-    np.fill_diagonal(rtt, 0.0)
-    out.update(adjacency=np.ones((r, r), bool), latency_penalty=pen,
-               rtt_s=rtt)
-    return out
-
-
-def build(grid_spec: dict, source_ci) -> dict:
-    kind = grid_spec["kind"]
-    if kind == "regions":
-        return regions_grid(grid_spec, source_ci)
-    raise ValueError(f"unknown grid kind {kind!r}")
 
 
 def component_table(g: dict) -> np.ndarray:

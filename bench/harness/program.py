@@ -1,16 +1,13 @@
 """The system under test: the program's router for a cell, built from the
-configuration's data through the program's public constructors, and the
-entry points the measured window drives.
-
-Every call copies its decisions back to the host, as a caller of the
-router would; those host arrays are what the check compares with the
-reference once the window has closed.
+configuration's data through the program's public constructors, with the
+policy that ``bench/policies/<config["policy_kind"]>.py`` builds and, on
+more than one chip, a mesh over the cell's chips. The entry points the
+measured window drives are ``bench/entries/<traffic["entry"]>.py``.
 """
 
 from __future__ import annotations
 
 import contextlib
-import time
 
 import jax
 import jax.numpy as jnp
@@ -18,23 +15,9 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.carbon_intensity import CarbonGrid
-from repro.core.infrastructure import (
-    ComputeSpec,
-    Fleet,
-    NetworkSpec,
-    pack_infra,
-)
-from repro.serve import (
-    FleetRouter,
-    OraclePolicy,
-    PlacementPolicy,
-    WorkerPool,
-    serve_stream,
-)
-from repro.serve.queue import BatchFormer
+from repro.core.infrastructure import ComputeSpec, Fleet, NetworkSpec
+from repro.serve import FleetRouter, data_mesh
 from repro.serve.router import RequestBatch
-
-from harness import cells
 
 _TIER_KEYS = ("name", "eff_flops", "eff_mem_bw", "p_comp", "p_comm",
               "p_idle", "ecf_lca_g", "lifetime_s", "pue")
@@ -68,15 +51,21 @@ def request_batch(stream) -> RequestBatch:
                         max_new_tokens=stream.max_new_tokens,
                         latency_budget_s=stream.latency_budget_s,
                         bytes_per_token=stream.bytes_per_token,
-                        available=stream.available)
+                        available=stream.available,
+                        slack_hours=stream.slack_hours)
 
 
-def build_router(cfg: dict, g: dict, caps: np.ndarray) -> FleetRouter:
+def build_router(cell, g: dict, caps: np.ndarray) -> FleetRouter:
+    """The program's router for ``cell``: its policy from
+    ``bench/policies/<policy_kind>.py`` built with ``caps``, and on more than
+    one chip a 1-D mesh over the cell's chips."""
+    cfg = cell.config
     fleet = fleet_of(cfg)
-    inner = OraclePolicy(pack_infra(fleet, cfg["embodied_model"]))
     return FleetRouter(get_config(cfg["model"]["name"]), fleet=fleet,
                        embodied_model=cfg["embodied_model"],
-                       grid=carbon_grid(g), policy=PlacementPolicy(inner, caps))
+                       grid=carbon_grid(g),
+                       policy=cell.policy().build(cfg, fleet, g, caps),
+                       mesh=data_mesh(cell.chips) if cell.chips > 1 else None)
 
 
 class Spans:
@@ -90,128 +79,6 @@ class Spans:
         if not self.on:
             return contextlib.nullcontext()
         return jax.profiler.TraceAnnotation(f"bench.{name}")
-
-
-class StepClock:
-    """Serve-step boundaries from the loop's per-step refit hook: handed to
-    ``serve_stream`` as its refitter, ``step()`` runs once at the end of
-    every step and never refits. A step lasts from the end of the one
-    before (or the call's start) through draft, route and commit."""
-
-    n_refits = 0
-
-    def __init__(self, spans: Spans, n_steps: int):
-        self.spans, self.n_steps = spans, n_steps
-        self.times: list[float] = []
-        self._span = None
-
-    def _open(self, name: str) -> None:
-        if self.spans.on:
-            self._span = self.spans(name)
-            self._span.__enter__()
-
-    def _close(self) -> None:
-        if self._span is not None:
-            self._span.__exit__(None, None, None)
-            self._span = None
-
-    def start(self) -> None:
-        self._t = time.perf_counter()
-        self._open("serve_step")
-
-    def observe(self, fr, fb, targets, committed) -> None:
-        pass
-
-    def step(self, fr):
-        now = time.perf_counter()
-        self.times.append(now - self._t)
-        self._t = now
-        self._close()
-        # the loop settles carbon after its last step
-        self._open("serve_step" if len(self.times) < self.n_steps
-                   else "serve_settle")
-        return fr, False
-
-    def stop(self) -> None:
-        self._close()
-
-
-class RouteEntry:
-    """One-shot day plan: ``FleetRouter.route_stream_with_state`` on one
-    device."""
-
-    def __init__(self, cfg, traffic, g, caps, streams, spans):
-        self.fr = build_router(cfg, g, caps)
-        self.spans = spans
-        self.inputs = [(request_batch(s), np.asarray(s.region, np.int32),
-                        np.asarray(s.t_hours),
-                        np.floor(s.t_hours).astype(np.int64) % 24)
-                       for s in streams]
-        self.step_s: list[float] = []
-        self.drafts: list[int] = []
-
-    def once(self, k: int) -> tuple[int, dict]:
-        batch, region, t_hours, hour = self.inputs[k]
-        with self.spans("route_call"):
-            res, state = self.fr.route_stream_with_state(batch, region,
-                                                         t_hours)
-            with self.spans("copy_back"):
-                out = dict(target=np.asarray(res.target),
-                           exec_region=np.asarray(res.exec_region),
-                           shed=np.asarray(state.shed),
-                           carbon_g=np.asarray(res.carbon_g))
-        out["exec_hour"] = hour
-        return len(region), out
-
-
-class ServeEntry:
-    """The online loop: ``repro.serve.queue.serve_stream`` over a day at
-    ``step_h``-hour steps, admission gated by a fresh ``WorkerPool`` per
-    day."""
-
-    def __init__(self, cfg, traffic, g, caps, streams, spans):
-        self.fr = build_router(cfg, g, caps)
-        self.spans = spans
-        self.pool_spec = dict(
-            cfg["capacity"]["pool"], tiers=cfg["capacity"]["dc_tiers"],
-            slots_per_worker=cells.slots_per_worker(
-                cfg, int(traffic["requests"]), g["ci_hourly"].shape[0]))
-        self.step_h = int(traffic["step_h"])
-        self.max_batch = int(traffic["max_batch"])
-        self.inputs = [(request_batch(s), np.asarray(s.region, np.int32),
-                        np.asarray(s.t_hours)) for s in streams]
-        self.step_s: list[float] = []
-        self.drafts: list[int] = []
-
-    def _pool(self) -> WorkerPool:
-        p = self.pool_spec
-        pool = WorkerPool(self.fr.grid.n_regions,
-                          slots_per_worker=p["slots_per_worker"],
-                          launch_delay_steps=p["launch_delay_steps"])
-        for r in range(self.fr.grid.n_regions):
-            for tier in p["tiers"]:
-                pool.launch(r, tier, n=p["workers"])
-        return pool
-
-    def once(self, k: int) -> tuple[int, dict]:
-        batch, region, t_hours = self.inputs[k]
-        clock = StepClock(self.spans, 24 // self.step_h)
-        with self.spans("serve_call"):
-            clock.start()
-            res = serve_stream(
-                self.fr, batch, region, t_hours, step_h=self.step_h,
-                pool=self._pool(), refitter=clock,
-                former=BatchFormer(max_batch=self.max_batch))
-            clock.stop()
-        self.step_s.extend(clock.times)
-        self.drafts.extend(s.n_batches for s in res.steps)
-        out = dict(target=res.target, exec_region=res.exec_region,
-                   exec_hour=res.exec_hour, shed=res.shed,
-                   carbon_g=res.carbon_g)
-        return len(region), out
-
-
-ENTRIES = {"route": RouteEntry, "serve": ServeEntry}
 
 
 def devices_used(n: int) -> list:

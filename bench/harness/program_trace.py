@@ -19,6 +19,7 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import json
+import os
 from pathlib import Path
 
 import numpy as np
@@ -26,8 +27,9 @@ import numpy as np
 from harness import cells, trace
 
 PROGRAM_PREFIX = "gs."
-#: where ``run.py`` writes the traced window's profile
-TRACE_DIR = cells.ROOT / ".bench_trace"
+#: where ``run.py`` writes the traced window's profile: one directory per
+#: process, so that runs in processes side by side never share one
+TRACE_DIR = cells.ROOT / ".bench_trace" / f"pid{os.getpid()}"
 
 
 @dataclasses.dataclass

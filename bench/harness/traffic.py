@@ -8,8 +8,10 @@ generator into columns.
 
 A class draws its prompt and output lengths from log-normal distributions
 around published medians, and its end-to-end latency budget from a
-time-to-first-token objective plus a per-output-token one. Arrival times
-follow the program's canonical diurnal curve
+time-to-first-token objective plus a per-output-token one. A class may
+carry ``slack_h``, the whole hours past arrival within which its requests
+may run; it draws nothing, so a mix's streams do not depend on whether a
+class sets it. Arrival times follow the program's canonical diurnal curve
 (``repro.serve.streams.diurnal_hours``), copied rather than imported
 because the yardstick must not move when the program does.
 """
@@ -34,6 +36,9 @@ class Stream:
     available: np.ndarray  # (N, 3) bool: [mobile, edge DC, hyperscale DC]
     region: np.ndarray  # (N,) int64 home region
     t_hours: np.ndarray  # (N,) float64 arrival time in [0, 24)
+    #: (N,) float64 whole hours past arrival a request may run; None when
+    #: no class of the mix sets ``slack_h``
+    slack_hours: np.ndarray | None = None
 
     def __len__(self) -> int:
         return len(self.prompt_tokens)
@@ -59,9 +64,9 @@ def request_mix(rng: np.random.Generator, n: int, mix: dict) -> dict:
     """Token counts, budgets and tier availability of ``n`` requests drawn
     from the mix's classes (each class: ``share``; ``prompt_median`` and
     ``new_median`` with log-normal ``sigma``; ``ttft_s`` and ``tpot_s``,
-    the budget being ``ttft_s + tpot_s * new tokens``). Prompt and output
-    together fit the model's ``context`` positions; every tier can serve
-    every request."""
+    the budget being ``ttft_s + tpot_s * new tokens``; optional
+    ``slack_h``, 0 where unset). Prompt and output together fit the
+    model's ``context`` positions; every tier can serve every request."""
     classes = mix["classes"]
     ctx = int(mix["context"])
     cls = rng.choice(len(classes), n, p=[c["share"] for c in classes])
@@ -73,11 +78,15 @@ def request_mix(rng: np.random.Generator, n: int, mix: dict) -> dict:
                                for c in classes])
     budget = np.select(conds, [c["ttft_s"] + c["tpot_s"] * new
                                for c in classes])
+    slack = None
+    if any("slack_h" in c for c in classes):
+        slack = np.select(conds, [float(c.get("slack_h", 0))
+                                  for c in classes])
     return dict(prompt_tokens=prompt.astype(np.float64),
                 max_new_tokens=new.astype(np.float64),
                 latency_budget_s=budget.astype(np.float64),
                 bytes_per_token=np.full(n, float(mix["bytes_per_token"])),
-                available=np.ones((n, 3), bool))
+                available=np.ones((n, 3), bool), slack_hours=slack)
 
 
 def arrivals(rng: np.random.Generator, n: int, n_regions: int,

@@ -40,9 +40,8 @@ sys.path.insert(0, str(ROOT / "src"))
 
 import numpy as np  # noqa: E402
 
-from harness import cells, check, grids, reference, traffic  # noqa: E402
+from harness import cells, check, program_trace, reference, traffic  # noqa: E402
 
-TRACE_DIR = ROOT / ".bench_trace"
 COMPILE_CACHE = ROOT / ".jax_compile_cache"
 
 
@@ -56,42 +55,23 @@ class Observed:
         self.step_s = list(entry.step_s)
         self.programs_in_window = programs_in_window
         self.calls = calls
+        #: the program's ``admit_rounds`` counter, read once the window has
+        #: closed: one value a call (day plan) or a draft (online loop)
+        self.admit_rounds = [int(x) for r in entry.admit_rounds
+                             for x in np.ravel(r)]
 
 
-def effective_caps(cell, caps) -> np.ndarray:
-    """(R, 3) float64 per-window admission limits the reference applies:
-    the configured caps, times the live worker slots in the online loop."""
-    caps = np.asarray(caps, np.float32).astype(np.float64)
-    if cell.traffic["entry"] != "serve":
-        return caps
-    c = cell.config["capacity"]
-    per_worker = cells.slots_per_worker(cell.config,
-                                        cell.traffic["requests"], len(caps))
-    slots = np.zeros_like(caps)
-    slots[:, c["dc_tiers"]] = np.float32(c["pool"]["workers"] * per_worker)
-    slots[:, 0] = np.inf
-    return caps * slots
-
-
-def cell_caps(cell, n_regions: int) -> np.ndarray:
-    """(R, 3) caps the program's policy is built with: mobile uncapped, each
-    DC tier its hourly capacity (``cells.dc_capacity``); unit caps in the
-    online loop, where live worker slots scale them."""
-    if cell.traffic["entry"] == "serve":
-        return np.ones((n_regions, 3))
-    caps = np.full((n_regions, 3), np.inf)
-    caps[:, cell.config["capacity"]["dc_tiers"]] = cells.dc_capacity(
-        cell.config, cell.traffic["requests"], n_regions)
-    return caps
-
-
-def reference_problem(cell, g, caps, stream, precision="highest"):
-    """The plain reference of one stream under the cell's deployment."""
-    serve = cell.traffic["entry"] == "serve"
-    return reference.Problem(
-        stream, cell.config, g, effective_caps(cell, caps),
-        reference.n_active_params(cell.config["model"]), precision,
-        serve_batch=int(cell.traffic["max_batch"]) if serve else None)
+def build(cell, seed: int, spans):
+    """Set-up of a run, up to the warm-up: the grid tables, the policy's
+    caps, the seeded streams and the cell's entry point over them."""
+    g = cell.grid()
+    n_regions = g["ci_hourly"].shape[0]
+    entry_cls = cell.entry()
+    caps = entry_cls.caps(cell, n_regions)
+    tr = cell.traffic
+    streams = [traffic.generate(tr, n_regions, traffic.stream_rng(seed, k))
+               for k in range(int(tr["streams"]))]
+    return g, caps, streams, entry_cls(cell, g, caps, streams, spans)
 
 
 def compare(outputs: list[dict], problem) -> list[dict]:
@@ -125,28 +105,23 @@ def run_cell(cell, seed: int, seconds: float, traced: bool,
     log = lambda msg: print(f"[bench {cell.name}] {msg}", file=sys.stderr,
                             flush=True)
 
-    g = grids.build(cell.config["grid"], cell.config["source_ci"])
-    n_regions = g["ci_hourly"].shape[0]
-    caps = cell_caps(cell, n_regions)
-    tr = cell.traffic
-    streams = [traffic.generate(tr, n_regions, traffic.stream_rng(seed, k))
-               for k in range(int(tr["streams"]))]
     spans = program.Spans(traced)
-    entry = program.ENTRIES[tr["entry"]](cell.config, tr, g, caps, streams,
-                                         spans)
+    g, caps, streams, entry = build(cell, seed, spans)
     for k in range(len(streams)):  # warm-up: every shape the window meets
         entry.once(k)
     entry.step_s.clear()
     entry.drafts.clear()
+    entry.admit_rounds.clear()
     setup_s = time.perf_counter() - t_start
     log(f"setup_s={setup_s} programs compiled={clock.compiles} "
         f"compile_s={clock.seconds} cache_hits={clock.cache_hits}")
 
+    trace_dir = program_trace.TRACE_DIR  # this process's own
     if traced:
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        shutil.rmtree(trace_dir, ignore_errors=True)
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
-        jax.profiler.start_trace(str(TRACE_DIR), profiler_options=opts)
+        jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
     programs0 = clock.programs
     outputs, decisions, k = [], 0, 0
     t0 = time.perf_counter()
@@ -165,7 +140,7 @@ def run_cell(cell, seed: int, seconds: float, traced: bool,
         jax.profiler.stop_trace()
         from harness import trace as trace_mod
         reduced = trace_mod.Reduced(
-            trace_mod.read_xplane(trace_mod.latest_xplane(str(TRACE_DIR))),
+            trace_mod.read_xplane(trace_mod.latest_xplane(str(trace_dir))),
             [f"{d.platform.upper()}:{d.id}"
              for d in program.devices_used(cell.chips)])
     peak = program.memory_peak_bytes(cell.chips)
@@ -177,10 +152,11 @@ def run_cell(cell, seed: int, seconds: float, traced: bool,
 
     # --- the check: every call against the reference of its stream -------
     t_ref = time.perf_counter()
+    ref = cell.reference()
     per_call = []
     for s_idx, stream in enumerate(streams):
         per_call += compare([out for j, out in outputs if j == s_idx],
-                            reference_problem(cell, g, caps, stream))
+                            ref.problem(cell, g, caps, stream))
     worst = check.worst(per_call)
     correct, table = check.judge(worst, cell.limits)
     log(f"reference_s={time.perf_counter() - t_ref} "
@@ -204,6 +180,7 @@ def run_cell(cell, seed: int, seconds: float, traced: bool,
             v = cells.metric_reader(m["name"], cell.bench_dir)(observed)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+        shutil.rmtree(trace_dir, ignore_errors=True)
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": peak}

@@ -1,5 +1,6 @@
 """BENCHMARK.json keeps to the benchmark's static rules, and every cell's
-pieces exist: configuration, traffic mix, limits, metric readers."""
+pieces exist: configuration, traffic mix, limits, metric readers, and the
+entry point, policy, reference and grid kind its files name."""
 
 import json
 import re
@@ -34,7 +35,13 @@ def test_configs_and_cells():
         assert NAME.match(c["name"]) and _line(c["why"]) and _line(
             c["source"])
         assert (ROOT / c["file"]).is_file()
-        assert json.loads((ROOT / c["file"]).read_text())["name"] == c["name"]
+        cfg = json.loads((ROOT / c["file"]).read_text())
+        assert cfg["name"] == c["name"]
+        for part in ("policies", "references"):
+            assert (ROOT / "bench" / part
+                    / f"{cfg['policy_kind']}.py").is_file()
+        assert (ROOT / "bench" / "grids"
+                / f"{cfg['grid']['kind']}.py").is_file()
     pairs = set()
     for w in SPEC["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
@@ -42,7 +49,9 @@ def test_configs_and_cells():
         assert w["chips"] in (1, 4) and w["config"] in configs
         assert (w["config"], w["traffic"]) not in pairs
         pairs.add((w["config"], w["traffic"]))
-        assert (ROOT / "bench" / "traffic" / f"{w['traffic']}.json").is_file()
+        mix = ROOT / "bench" / "traffic" / f"{w['traffic']}.json"
+        entry = json.loads(mix.read_text())["entry"]
+        assert (ROOT / "bench" / "entries" / f"{entry}.py").is_file()
         assert (ROOT / "bench" / "limits" / f"{w['name']}.json").is_file()
     used = {w["config"] for w in SPEC["workloads"]}
     assert used == set(configs)

@@ -5,7 +5,7 @@ correct under every cell's limits."""
 import pytest
 
 import run
-from harness import cells, check, grids, traffic
+from harness import cells, check, traffic
 
 from calibrate import as_output
 
@@ -13,13 +13,13 @@ from calibrate import as_output
 @pytest.mark.parametrize("name", ["dense4.place", "dense4.serve"])
 def test_control_fails_the_check(small_cell, name):
     cell = small_cell(name, 20000)
-    g = grids.build(cell.config["grid"], cell.config["source_ci"])
-    caps = run.cell_caps(cell, g["ci_hourly"].shape[0])
+    g = cell.grid()
+    caps = cell.entry().caps(cell, g["ci_hourly"].shape[0])
     stream = traffic.generate(cell.traffic, g["ci_hourly"].shape[0],
                               traffic.stream_rng(3, 0))
-    ref = run.reference_problem(cell, g, caps, stream)
-    ctrl = as_output(run.reference_problem(cell, g, caps, stream,
-                                           precision="high").solve())
+    ref = cell.reference().problem(cell, g, caps, stream)
+    ctrl = as_output(cell.reference().problem(cell, g, caps, stream,
+                                              precision="high").solve())
     own = as_output(ref.solve())
     ok_ref, _ = check.judge(check.worst(run.compare([own], ref)),
                             cells.load(name).limits)

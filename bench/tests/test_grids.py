@@ -20,8 +20,7 @@ def _config(name):
      lambda: CarbonGrid.fully_connected(DEFAULT_REGIONS, latency_penalty=1.05)),
 ])
 def test_grid_tables_match_the_program(cell, build):
-    cfg = _config(cell)
-    ours = grids.build(cfg["grid"], cfg["source_ci"])
+    ours = cells.load(cell).grid()
     theirs = build()
     for f in ("ci_hourly", "ci_mobile", "ci_core", "pue", "latency_penalty",
               "rtt_s"):
