@@ -147,23 +147,28 @@ def device_prefix_ranks(rank: jax.Array, totals: jax.Array, cell: jax.Array,
     device sum of the same gather (== psum). All int32 counting arithmetic
     — the reconciliation is exact, which is what makes sharded admission
     bit-identical to the single-device program. ``axis_name=None`` is the
-    single-device identity."""
+    single-device identity. The cross-device step carries the device
+    scope ``reconcile``; at one device there is no such step."""
     if axis_name is None:
         return rank, totals
-    all_totals = jax.lax.all_gather(totals, axis_name)  # (D, n_cells)
-    prior = (jnp.cumsum(all_totals, axis=0)[jax.lax.axis_index(axis_name)]
-             - totals)  # exclusive prefix over earlier devices
-    return rank + prior[cell], all_totals.sum(axis=0)
+    with jax.named_scope("reconcile"):
+        all_totals = jax.lax.all_gather(totals, axis_name)  # (D, n_cells)
+        prior = (jnp.cumsum(all_totals, axis=0)
+                 [jax.lax.axis_index(axis_name)]
+                 - totals)  # exclusive prefix over earlier devices
+        return rank + prior[cell], all_totals.sum(axis=0)
 
 
 def _global_any(pred: jax.Array, axis_name: str | None) -> jax.Array:
     """``pred.any()`` across the mesh axis (identity when unsharded) — the
     sharded admission loops must keep spinning while ANY device still has
     an open-celled contender, or devices would exit the collective loop at
-    different trip counts and deadlock."""
+    different trip counts and deadlock. Scope ``reconcile``, as in
+    ``device_prefix_ranks``."""
     if axis_name is None:
         return pred
-    return jax.lax.psum(pred.astype(jnp.int32), axis_name) > 0
+    with jax.named_scope("reconcile"):
+        return jax.lax.psum(pred.astype(jnp.int32), axis_name) > 0
 
 
 @dataclasses.dataclass

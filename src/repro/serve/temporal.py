@@ -77,10 +77,11 @@ class TemporalState:
                     full.
     ``exec_region`` (N,) int32 — executing region (home for shed rows).
     ``shed_pair``   (R, 3) int32 — shed demand keyed by first-choice pair.
-    ``exec_hour``   (N,) int32 — hour-of-day the request executes in
-                    (== arrival hour for undeferred, shed, and unroutable
-                    rows). The fleet router accounts carbon under THIS
-                    hour's CI.
+    ``exec_hour``   (N,) int32 — absolute hour of the grid's horizon the
+                    request executes in (arrival hour + ``defer_hours``;
+                    == the arrival hour for undeferred, shed, and
+                    unroutable rows; past 23 on a multi-day grid). The
+                    fleet router accounts carbon under THIS hour's CI.
     ``defer_hours`` (N,) int32 — hours deferred past arrival; always within
                     ``[0, slack]`` (property-tested).
     ``admit_rounds`` () int32 — admission rounds run (the ``while_loop``'s

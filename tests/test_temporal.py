@@ -6,6 +6,10 @@ WAN-hop (rtt_s) QoS satellite."""
 
 import dataclasses
 import functools
+import json
+import os
+import subprocess
+import sys
 
 import hypothesis
 import hypothesis.strategies as st
@@ -709,6 +713,63 @@ class TestMultiDayHorizon:
                                & ~shed).sum())
                     assert got <= caps[r, t], (h, r, t, got)
         assert adjacency[region[~shed], ex[~shed]].all()
+
+
+DAY_AHEAD = r"""
+import json, os
+os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4 "
+                           + os.environ.get("XLA_FLAGS", ""))
+import numpy as np
+from repro.configs import get_config
+from repro.core.carbon_intensity import DEFAULT_REGIONS, CarbonGrid
+from repro.serve import FleetRouter, OraclePolicy, TemporalPolicy, data_mesh
+from repro.serve.streams import deferrable_stream
+
+cfg = get_config("h2o-danube-1.8b")
+r, n = len(DEFAULT_REGIONS), 3000
+caps = np.full((r, 3), np.inf)
+caps[:, 1:] = n / (r * 24)
+grid = CarbonGrid.fully_connected(DEFAULT_REGIONS,
+                                  latency_penalty=1.05).repeat(2)
+fr = FleetRouter(cfg, grid=grid, policy=TemporalPolicy(
+    OraclePolicy(FleetRouter(cfg).infra), caps, max_defer_h=23))
+batch, region, t = deferrable_stream(n, r, seed=7, slack_range_h=(23, 23))
+out = {}
+for d in (None, 4):
+    res, st = fr.route_stream_with_state(
+        batch, region, t, mesh=None if d is None else data_mesh(d))
+    out[str(d)] = {k: np.asarray(v).tolist() for k, v in dict(
+        target=res.target, exec_region=st.exec_region,
+        exec_hour=st.exec_hour, shed=st.shed).items()}
+print("DECISIONS", json.dumps(dict(out, arrival=t.tolist(),
+                                   slack=batch.slack_h.tolist())))
+"""
+
+
+def test_day_ahead_batch_window_on_two_day_horizon():
+    """A 24-h completion window (slack 23) on a 2-day repeated grid: every
+    row executes at an absolute hour within [arrival, arrival + slack],
+    rows arriving late in day one run in day two, and the decisions of
+    the one-device program and of the sharded one on 4 fake devices are
+    bitwise equal (a fresh process: the only place the device-count
+    override may exist)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", DAY_AHEAD],
+                          capture_output=True, text=True, timeout=600,
+                          cwd=root, env={**os.environ, "PYTHONPATH": "src",
+                                         "JAX_PLATFORMS": "cpu"})
+    line = [ln for ln in proc.stdout.splitlines()
+            if ln.startswith("DECISIONS")]
+    assert line, proc.stderr[-2000:]
+    got = json.loads(line[0].split(" ", 1)[1])
+    assert got["None"] == got["4"]
+    eh = np.asarray(got["None"]["exec_hour"])
+    arr = np.floor(got["arrival"]).astype(int)
+    slack = np.asarray(got["slack"])
+    assert ((eh >= arr) & (eh <= arr + slack)).all()
+    late = (arr >= 18) & (slack == 23) & ~np.asarray(got["None"]["shed"])
+    assert late.any() and (eh[late] >= 24).any()
+    assert (eh > 23).any() and eh.max() <= 46
 
 
 class TestLearnedFactorized:

@@ -1,6 +1,7 @@
 """Ahead-of-time compiles for a described TPU v5e chip (no chip attached):
-the routing programs of ``chip_smoke.py`` at their real stream sizes must
-compile and fit one chip's 16 GiB, and every Pallas kernel must get past
+the routing programs of ``chip_smoke.py`` at their real stream sizes, and
+the benchmark's four-chip deferral day plan, must compile and fit one
+chip's 16 GiB, and every Pallas kernel must get past
 Mosaic at one 128-aligned shape.
 
 The topology is described inside a module fixture, never at import: only
@@ -19,8 +20,10 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 import chip_smoke
+from repro.configs import get_config
 from repro.kernels import ops
-from repro.serve import distributed
+from repro.serve import FleetRouter, OraclePolicy, TemporalPolicy, distributed
+from repro.serve.streams import deferrable_stream
 
 #: one v5e chip's HBM
 CHIP_BYTES = 16 * 2**30
@@ -81,22 +84,44 @@ def test_fleet_route_compiles_for_one_chip(make, n, one_chip):
     _fits_one_chip(fr._fleet_route.lower(*args).compile())
 
 
-def test_sharded_route_compiles_for_four_chips(topo):
-    """The place_1m stream through the shard_map program on a 4-chip mesh
-    of the described devices: per-row inputs split over the mesh, grid
-    tables replicated."""
-    fr, batch, region, hour = _phase_args(chip_smoke.place_phase(1_000_000))
+def _compiles_sharded(topo, fr, batch, region, t_hours):
+    """The router's shard_map program on a 4-chip mesh of the described
+    devices (per-row inputs split over the mesh, grid tables replicated)
+    fits each chip and reconciles admission with an ``all-gather``."""
+    hour = (np.floor(t_hours) % fr.grid.horizon_h).astype(np.int32)
     mesh = Mesh(np.asarray(topo.devices[:4]), (distributed.DATA_AXIS,))
     program = distributed._build_sharded_route(fr, mesh,
                                                distributed.DATA_AXIS)
-    rows, _ = distributed.shard_stream(fr, batch, region, hour,
-                                       distributed.data_mesh(1))
+    rows, _ = distributed.shard_stream(fr, batch, np.asarray(region, np.int32),
+                                       hour, distributed.data_mesh(1))
     tables = (fr._ci_table, fr._ci_fc)
     args = (*_specs(rows, NamedSharding(mesh, P(distributed.DATA_AXIS))),
             *_specs(tables, NamedSharding(mesh, P())), None, None)
     compiled = program.lower(*args).compile()
     _fits_one_chip(compiled)
     assert "all-gather" in compiled.as_text()
+
+
+def test_sharded_route_compiles_for_four_chips(topo):
+    """The place_1m stream through the shard_map program on a 4-chip
+    mesh."""
+    phase = chip_smoke.place_phase(1_000_000)
+    _compiles_sharded(topo, phase.build(), *phase.stream)
+
+
+def test_sharded_deferral_compiles_for_four_chips(topo):
+    """A day of 1,000,000 requests, half of them batch work that may wait
+    23 hours, on a 48-hour repeated grid through the shard_map program of
+    ``TemporalPolicy``: the (N, 24, 4, 3) candidate program fits each chip
+    only because the rows are split four ways."""
+    n, r = 1_000_000, 4
+    cfg = get_config(chip_smoke.ARCH)
+    policy = TemporalPolicy(OraclePolicy(FleetRouter(cfg).infra),
+                            chip_smoke._dc_caps(r, n / (r * 24)),
+                            max_defer_h=23)
+    fr = FleetRouter(cfg, grid=chip_smoke._grid4().repeat(2), policy=policy)
+    _compiles_sharded(topo, fr, *deferrable_stream(n, r,
+                                                   slack_range_h=(23, 23)))
 
 
 def _kernel_cases():

@@ -238,6 +238,57 @@ print("ROUNDS", json.dumps(out))
         assert 1 < rounds[0] < 24 * R * 3 + 1, (kind, rounds)
 
 
+def test_reconcile_scope_only_on_the_sharded_program():
+    """The cross-device step of sharded admission (the per-round
+    ``all_gather`` of contender counts and the any-device loop test)
+    carries the device scope ``reconcile`` on 4 fake devices, inside
+    ``admit``; the one-device program has no such step and no op under
+    the scope, and the sharded decisions are the one-device program's
+    bit for bit."""
+    code = r"""
+import json, os
+os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4 "
+                           + os.environ.get("XLA_FLAGS", ""))
+import numpy as np
+from test_tracing import _router, _stream
+from repro.configs import get_config
+from repro.serve import FleetRouter, data_mesh, distributed
+cfg = get_config("h2o-danube-1.8b")
+fr = _router(cfg, FleetRouter(cfg).infra, "temporal", 6.0)
+batch, region, t = _stream(515, seed=3, slack=True)
+hour = (np.floor(t) % fr._horizon_h).astype(np.int32)
+region = np.asarray(region, np.int32)
+mesh = data_mesh(4)
+rows, _ = distributed.shard_stream(fr, batch, region, hour, mesh)
+sharded = distributed._build_sharded_route(fr, mesh, distributed.DATA_AXIS)
+text = sharded.lower(*rows, fr._ci_table, fr._ci_fc, None,
+                     None).compile().as_text()
+one = fr._fleet_route.lower(*fr._route_args(batch, region,
+                                            hour)).compile().as_text()
+names = lambda text: [ln.split('op_name="')[1].split('"')[0].split("/")
+                      for ln in text.splitlines() if 'op_name="' in ln]
+digest = lambda res, st: [np.asarray(a).tolist() for a in (
+    res.target, res.exec_region, st.exec_hour, st.shed)]
+print("SCOPES", json.dumps(dict(
+    sharded=[n for n in names(text) if "reconcile" in n],
+    one=[n for n in names(one) if "reconcile" in n],
+    one_admit=sum("admit" in n for n in names(one)),
+    same=digest(*fr.route_stream_with_state(batch, region, t))
+    == digest(*fr.route_stream_with_state(batch, region, t, mesh=mesh)))))
+"""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=300, cwd=root,
+                          env={**os.environ, "PYTHONPATH": f"src:{root}/tests",
+                               "JAX_PLATFORMS": "cpu"})
+    line = [ln for ln in proc.stdout.splitlines() if ln.startswith("SCOPES")]
+    assert line, proc.stderr[-2000:]
+    got = json.loads(line[0].split(" ", 1)[1])
+    assert got["sharded"] and all("admit" in n for n in got["sharded"])
+    assert any("all_gather" in n for n in got["sharded"])
+    assert got["one"] == [] and got["one_admit"] > 0
+    assert got["same"]
+
 def _digest(*arrays) -> str:
     h = hashlib.sha256()
     for a in arrays:
