@@ -26,12 +26,16 @@ the per-cell capacity ledger reconciled across devices between spill rounds:
     ``enable_compile_cache`` wires jax's persistent compilation cache so
     the large admission jits compile once across process restarts.
 
-Aggregates (carbon sums, shed/spill/defer counts) are computed HOST-side
-from the bit-identical per-row arrays, so every ``FleetRouteResult`` field
-is deterministic in the device count. Per-row policies (``OraclePolicy``,
-``LearnedPolicy``) shard trivially (no collectives); ``CapacityLimiter``'s
-sequential ``lax.scan`` cannot reconcile and is refused with a pointer to
-its bit-identical ``PlacementPolicy`` replacement.
+Nothing comes back to the host: a second, small jitted program on the mesh
+unsorts the per-row outputs into stream order (they stay split over the
+mesh axis, so a caller copies them back from every chip at once) and sums
+the aggregates (carbon sums, counts, shed/spill/defer counts) on the
+device. Decisions are bit-identical at every device count; the float sums
+agree with the single-device program's to float rounding. Per-row
+policies (``OraclePolicy``, ``LearnedPolicy``) shard trivially (no
+collectives); ``CapacityLimiter``'s sequential ``lax.scan`` cannot
+reconcile and is refused with a pointer to its bit-identical
+``PlacementPolicy`` replacement.
 
 Surface: ``FleetRouter(mesh=...)`` (or ``route_stream(..., mesh=...)``)
 routes every stream through this module — ``serve_stream`` and the rolling
@@ -57,11 +61,10 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import carbon_model
 from repro.core.carbon_model import Environment
-from repro.core.constants import N_TARGETS
 from repro.serve.forecast import slice_batch
 from repro.serve.placement import PlacementState
 from repro.serve.policy import CapacityLimiter
-from repro.serve.router import FleetRouteResult
+from repro.serve.router import FleetRouteResult, pair_counts_and_deferral
 from repro.serve.temporal import TemporalState
 
 #: canonical name of the 1-D routing mesh axis (matches ``launch.mesh``'s
@@ -121,11 +124,11 @@ def _check_mesh(mesh: Mesh) -> str:
 
 def _build_sharded_route(fr, mesh: Mesh, axis: str):
     """The jitted shard_map routing program for one (router, mesh) pair —
-    mirrors ``FleetRouter._fleet_route`` but returns PER-ROW arrays only
-    (aggregation happens on the host, deterministically in the device
-    count). Replicated outputs are returned device-tiled (leading axis
-    ``D``) because ``check_vma=False`` — required for the admission
-    while-loops — forbids unmentioned-axis out_specs."""
+    mirrors ``FleetRouter._fleet_route`` but returns PER-ROW arrays only,
+    in sorted and padded order (``_build_aggregate``'s program unsorts and
+    sums them on the mesh). Replicated outputs are returned device-tiled
+    (leading axis ``D``) because ``check_vma=False`` — required for the
+    admission while-loops — forbids unmentioned-axis out_specs."""
     policy = fr.policy
     infra = fr._infra
     interference = fr._interference
@@ -219,9 +222,9 @@ def _build_sharded_route(fr, mesh: Mesh, axis: str):
                 ref_energy=take_act(out.target_energy),
                 ref_oracle=take_act(out.target),
             )
-        # replicated state pieces, device-tiled for the out_spec (host
-        # reads shard 0; parity across shards is exactly what the
-        # reconciliation guarantees and the invariance suite pins)
+        # replicated state pieces, device-tiled for the out_spec (the
+        # aggregation reads shard 0; parity across shards is exactly what
+        # the reconciliation guarantees and the invariance suite pins)
         tiled = dict(
             counts=getattr(new_state, "counts", None),
             shed_pair=getattr(new_state, "shed_pair", None),
@@ -248,14 +251,17 @@ def _build_sharded_route(fr, mesh: Mesh, axis: str):
 
 
 def _program_for(fr, mesh: Mesh, axis: str, sig):
-    """One compiled program per (router, mesh, optional-arg signature) —
-    rebuilding the shard_map wrapper per call would discard jit's compile
-    cache. ``sig`` captures which optional args are None (they change the
-    traced pytree structure)."""
+    """The ``(route, aggregate)`` pair of compiled programs per (router,
+    mesh, optional-arg signature) — rebuilding the shard_map wrapper per
+    call would discard jit's compile cache. ``sig`` captures which optional
+    args are None (they change the traced pytree structure). The
+    aggregation is a program of its own so that it adds nothing to the
+    admission program's compile or memory."""
     cache = fr.__dict__.setdefault("_sharded_programs", {})
     key = (mesh, axis, sig)
     if key not in cache:
-        cache[key] = _build_sharded_route(fr, mesh, axis)
+        cache[key] = (_build_sharded_route(fr, mesh, axis),
+                      _build_aggregate(fr, mesh, axis))
     return cache[key]
 
 
@@ -306,6 +312,86 @@ def shard_stream(fr, batch, region_np, hour_np, mesh, slack_np=None):
     return rows, inv_np
 
 
+def _row_sharding(mesh: Mesh, axis: str, n: int) -> NamedSharding:
+    """Sharding of an (n,) per-row result in stream order: split over the
+    mesh axis, or replicated over the mesh where ``n`` is not a device
+    multiple (a jax array cannot split a dimension unevenly; no chip is
+    singled out either way)."""
+    split = n % int(mesh.devices.size) == 0
+    return NamedSharding(mesh, P(axis) if split else P())
+
+
+def _build_aggregate(fr, mesh: Mesh, axis: str):
+    """The jitted aggregation program that follows the shard_map program:
+    unsort the per-row outputs into stream order (one gather each by the
+    inverse permutation, which drops the pads), keep them on the mesh, and
+    sum every ``FleetRouteResult`` aggregate on the device with the
+    formulas of the single-device program. The permutation-invariant
+    reference sums (``refs``) are taken in sorted order, pads masked. The
+    per-row columns it unsorts are donated: their outputs reuse their
+    memory."""
+    policy = fr.policy
+    n_regions = len(fr.regions)
+    diag = bool(getattr(policy, "_diag_only", False))
+    replicated = NamedSharding(mesh, P())
+
+    def _aggregate(per_row, refs, tiled, inv, region):
+        n = inv.shape[0]
+        rows = _row_sharding(mesh, axis, n)
+        on_rows = lambda a: jax.lax.with_sharding_constraint(a, rows)
+        one = lambda a: jax.lax.with_sharding_constraint(a, replicated)
+        with jax.named_scope("aggregate"):
+            row = lambda a: None if a is None else on_rows(a.at[inv].get(
+                mode="promise_in_bounds", unique_indices=True))
+            target = row(per_row["target"])
+            carbon = row(per_row["carbon"])
+            feas = row(per_row["feas"])
+            exec_region = row(per_row["exec_region"])
+            shed = row(per_row["shed"])
+            exec_hour = row(per_row["exec_hour"])
+            defer = row(per_row["defer"])
+            live = (jnp.ones_like(feas) if shed is None else ~shed)
+            counts, deferred, mean_defer = pair_counts_and_deferral(
+                exec_region, target, shed, defer, n_regions)
+            spilled = ((exec_region != region) & live).sum()
+            valid = jnp.arange(per_row["target"].shape[0]) < n
+            sorted_sum = lambda a: jnp.where(valid, a, 0).sum()
+            res = FleetRouteResult(
+                target=target,
+                carbon_g=carbon,
+                feasible=feas,
+                counts=one(counts),
+                total_carbon_g=one(carbon.sum()),
+                routed_carbon_g=one(jnp.where(live, carbon, 0).sum()),
+                latency_opt_carbon_g=one(sorted_sum(refs["ref_latency"])),
+                energy_opt_carbon_g=one(sorted_sum(refs["ref_energy"])),
+                oracle_carbon_g=one(sorted_sum(refs["ref_oracle"])),
+                infeasible_count=one((~feas).sum().astype(jnp.int32)),
+                shed_count=one((~live).sum().astype(jnp.int32)),
+                exec_region=exec_region,
+                spilled_count=one(spilled.astype(jnp.int32)),
+                deferred_count=one(deferred),
+                mean_defer_hours=one(mean_defer),
+            )
+            if tiled["counts"] is None:  # stateless per-row policy
+                return res, ()
+            # replicated state pieces: shard 0 of the device-tiled outputs
+            first = {k: one(v[0]) for k, v in tiled.items()}
+            if exec_hour is not None:
+                return res, TemporalState(
+                    counts=first["counts"], shed=shed,
+                    exec_region=exec_region, shed_pair=first["shed_pair"],
+                    exec_hour=exec_hour, defer_hours=defer,
+                    admit_rounds=first["admit_rounds"])
+            return res, PlacementState(
+                counts=first["counts"], shed=shed,
+                exec_region=None if diag else exec_region,
+                shed_pair=first["shed_pair"],
+                admit_rounds=first["admit_rounds"])
+
+    return jax.jit(_aggregate, donate_argnums=(0,))
+
+
 def route_arrays_sharded(fr, batch, region_np, hour_np, mesh, *,
                          ci_fc=None, cap_scale=None, used0=None,
                          slack_np=None):
@@ -313,10 +399,15 @@ def route_arrays_sharded(fr, batch, region_np, hour_np, mesh, *,
     contract, same ``(FleetRouteResult, state)`` return, decisions
     bit-identical to the single-device program at any device count.
 
-    Host side: ``shard_stream`` sorts, pads and shards the stream; run the
-    shard_map program; slice the pads off, unsort, and aggregate per-row
-    outputs with numpy. Host spans: ``gs.route.prepare``,
-    ``gs.route.dispatch`` and ``gs.route.aggregate``."""
+    Host side: ``shard_stream`` sorts, pads and shards the stream, and the
+    inverse permutation and the home regions are uploaded in stream order;
+    then the shard_map program and the aggregation program are dispatched
+    one after the other. Nothing comes back to the host: the per-row
+    results stay on the mesh in stream order, the aggregates are summed on
+    the device, and the call returns without blocking, as the
+    single-device program does. Host spans: ``gs.route.prepare``,
+    ``gs.route.dispatch`` and ``gs.route.aggregate`` (the dispatch of the
+    aggregation program)."""
     policy = fr.policy
     if isinstance(policy, CapacityLimiter):
         raise NotImplementedError(
@@ -329,89 +420,17 @@ def route_arrays_sharded(fr, batch, region_np, hour_np, mesh, *,
     with TraceAnnotation("gs.route.prepare"):
         rows, inv_np = shard_stream(fr, batch, region_np, hour_np, mesh,
                                     slack_np)
+        inv, region = jax.device_put(
+            (inv_np, region_np), _row_sharding(mesh, axis, len(inv_np)))
 
     # --- run the sharded program ------------------------------------------
     with TraceAnnotation("gs.route.dispatch"):
         sig = (ci_fc is None, cap_scale is None, used0 is None)
-        program = _program_for(fr, mesh, axis, sig)
+        program, aggregate = _program_for(fr, mesh, axis, sig)
         per_row, tiled = program(
             *rows, fr._ci_table, fr._ci_fc if ci_fc is None else ci_fc,
             cap_scale, used0)
     with TraceAnnotation("gs.route.aggregate"):
-        return _aggregate(fr, policy, per_row, tiled, inv_np, region_np)
-
-
-def _aggregate(fr, policy, per_row, tiled, inv_np, region_np):
-    """Unpad, unsort and aggregate the sharded program's per-row outputs
-    on the host into the ``(FleetRouteResult, state)`` pair."""
-    n = len(inv_np)
-    n_regions = len(fr.regions)
-    row = lambda a: None if a is None else np.asarray(a)[:n][inv_np]
-    target = row(per_row["target"])
-    carbon = row(per_row["carbon"])
-    feas = row(per_row["feas"])
-    exec_region = row(per_row["exec_region"])
-    shed = row(per_row["shed"])
-    defer = row(per_row["defer"])
-    shed_b = np.zeros(n, bool) if shed is None else shed
-    routed = carbon[~shed_b].sum(dtype=np.float32)
-    pair = exec_region * N_TARGETS + target
-    counts = np.bincount(pair[~shed_b], minlength=n_regions * N_TARGETS
-                         ).astype(np.int32).reshape(n_regions, N_TARGETS)
-    spilled = int(((exec_region != region_np) & ~shed_b).sum())
-    if defer is None:
-        deferred, mean_defer = 0, np.float32(0.0)
-    else:
-        dmask = (defer > 0) & ~shed_b
-        deferred = int(dmask.sum())
-        mean_defer = np.float32(
-            defer[dmask].sum() / max(deferred, 1))
-    res = FleetRouteResult(
-        target=jnp.asarray(target),
-        carbon_g=jnp.asarray(carbon),
-        feasible=jnp.asarray(feas),
-        counts=jnp.asarray(counts),
-        total_carbon_g=jnp.asarray(carbon.sum(dtype=np.float32)),
-        routed_carbon_g=jnp.asarray(routed),
-        latency_opt_carbon_g=jnp.asarray(
-            row(per_row["ref_latency"]).sum(dtype=np.float32)),
-        energy_opt_carbon_g=jnp.asarray(
-            row(per_row["ref_energy"]).sum(dtype=np.float32)),
-        oracle_carbon_g=jnp.asarray(
-            row(per_row["ref_oracle"]).sum(dtype=np.float32)),
-        infeasible_count=jnp.asarray(np.int32((~feas).sum())),
-        shed_count=jnp.asarray(np.int32(shed_b.sum())),
-        exec_region=jnp.asarray(exec_region),
-        spilled_count=jnp.asarray(np.int32(spilled)),
-        deferred_count=jnp.asarray(np.int32(deferred)),
-        mean_defer_hours=jnp.asarray(mean_defer),
-    )
-    state = _rebuild_state(policy, per_row, tiled, row)
-    return res, state
-
-
-def _rebuild_state(policy, per_row, tiled, row):
-    """Reassemble the policy's state object from the program's per-row and
-    device-tiled outputs (shard 0 of the tiled pieces — replicated by the
-    reconciliation)."""
-    counts = tiled.get("counts")
-    if counts is None:  # stateless per-row policy
-        return ()
-    counts = jnp.asarray(np.asarray(counts)[0])
-    shed_pair = jnp.asarray(np.asarray(tiled["shed_pair"])[0])
-    admit_rounds = jnp.asarray(np.asarray(tiled["admit_rounds"])[0])
-    shed = jnp.asarray(row(per_row["shed"]))
-    if per_row["exec_hour"] is not None:
-        return TemporalState(
-            counts=counts, shed=shed,
-            exec_region=jnp.asarray(row(per_row["exec_region"])),
-            shed_pair=shed_pair,
-            exec_hour=jnp.asarray(row(per_row["exec_hour"])),
-            defer_hours=jnp.asarray(row(per_row["defer"])),
-            admit_rounds=admit_rounds)
-    diag = bool(getattr(policy, "_diag_only", False))
-    return PlacementState(
-        counts=counts, shed=shed,
-        exec_region=(None if diag
-                     else jnp.asarray(row(per_row["exec_region"]))),
-        shed_pair=shed_pair, admit_rounds=admit_rounds)
+        refs = {k: per_row.pop(k)
+                for k in ("ref_latency", "ref_energy", "ref_oracle")}
+        return aggregate(per_row, refs, tiled, inv, region)

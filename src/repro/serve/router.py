@@ -278,6 +278,29 @@ class GreenScaleRouter:
 _admit_windows_warned = False
 
 
+def pair_counts_and_deferral(exec_region, targets, shed, defer,
+                             n_regions: int):
+    """A call's (region, tier) counts over the rows not shed, its deferred
+    row count and their mean deferral in hours. ``shed`` and ``defer`` may
+    be None (nothing shed, nothing deferred). The counts are a one-hot
+    reduction over the flattened pair index — a dense sum, not an N-wide
+    scatter."""
+    pair = exec_region * N_TARGETS + targets
+    one_hot = jax.nn.one_hot(pair, n_regions * N_TARGETS, dtype=jnp.int32)
+    if shed is not None:
+        one_hot = one_hot * (~shed)[:, None].astype(jnp.int32)
+    counts = one_hot.sum(axis=0).reshape(n_regions, N_TARGETS)
+    if defer is None:
+        return counts, jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32)
+    dmask = defer > 0
+    if shed is not None:
+        dmask = dmask & ~shed
+    deferred = dmask.sum().astype(jnp.int32)
+    mean_defer = ((defer * dmask).sum()
+                  / jnp.maximum(deferred, 1)).astype(jnp.float32)
+    return counts, deferred, mean_defer
+
+
 def _warn_admit_windows() -> None:
     """Warn ONCE per process that bucketed admission is deprecated."""
     global _admit_windows_warned
@@ -580,25 +603,9 @@ class FleetRouter:
                                        take_act(targets))
                     feas = jnp.where(moved, take2(out_exec.ok, targets),
                                      take2(out.ok, targets))
-                # (region, tier) assignment counts as a one-hot reduction over
-                # the flattened pair index — a dense sum, not an N-wide scatter
-                pair = exec_region * N_TARGETS + targets
-                one_hot = jax.nn.one_hot(pair, n_regions * N_TARGETS,
-                                         dtype=jnp.int32)
-                if shed is not None:
-                    one_hot = one_hot * (~shed)[:, None].astype(jnp.int32)
-                counts = one_hot.sum(axis=0).reshape(n_regions, N_TARGETS)
-                defer = getattr(new_state, "defer_hours", None)
-                if defer is None:
-                    deferred = jnp.zeros((), jnp.int32)
-                    mean_defer = jnp.zeros((), jnp.float32)
-                else:
-                    dmask = defer > 0
-                    if shed is not None:
-                        dmask = dmask & ~shed
-                    deferred = dmask.sum().astype(jnp.int32)
-                    mean_defer = ((defer * dmask).sum()
-                                  / jnp.maximum(deferred, 1)).astype(jnp.float32)
+                counts, deferred, mean_defer = pair_counts_and_deferral(
+                    exec_region, targets, shed,
+                    getattr(new_state, "defer_hours", None), n_regions)
                 return FleetRouteResult(
                     target=targets,
                     carbon_g=carbon,

@@ -3,13 +3,15 @@
 bit-identical to the single-device program — in-process on a 1-device mesh,
 and at 1/2/4/8 fake devices in a subprocess, the only place the XLA
 device-count override may exist), property-based conservation/caps
-invariants lifted onto the sharded path, buffer-donation probes for the
-routing and settle jits, mesh-aware ``BatchFormer`` padding, and the
-``CapacityLimiter`` refusal."""
+invariants lifted onto the sharded path, the mesh-side aggregation against
+the host formulas, buffer-donation probes for the routing and settle jits,
+mesh-aware ``BatchFormer`` padding, and the ``CapacityLimiter`` refusal."""
 
+import json
 import os
 import subprocess
 import sys
+from types import SimpleNamespace
 
 import hypothesis
 import hypothesis.strategies as st
@@ -18,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.core import carbon_model
@@ -72,9 +75,10 @@ def mesh1():
     return data_mesh(1)
 
 
-def _routers(cfg, base):
-    """The parity matrix: every admission mode the reconciliation covers."""
-    caps = np.full((N_REGIONS, 3), 30.0)
+def _routers(cfg, base, cap: float = 30.0):
+    """The parity matrix: every admission mode the reconciliation covers,
+    each DC and mobile tier admitting ``cap`` requests a window."""
+    caps = np.full((N_REGIONS, 3), cap)
     xgrid = CarbonGrid.fully_connected(DEFAULT_REGIONS)
     mk = lambda **kw: FleetRouter(cfg, **kw)
     return {
@@ -179,6 +183,192 @@ class TestShardedParity:
         batch, region, t = _stream(32, seed=2)
         with pytest.raises(ValueError, match="ONE data axis"):
             FleetRouter(cfg).route_stream(batch, region, t, mesh=mesh2)
+
+
+def _host_aggregates(res, state, region, n_regions=N_REGIONS):
+    """The aggregates as the host formulas give them from the returned
+    per-row columns: counts by bincount over rows that were not shed,
+    float32 sums, and the shed/spill/defer counts."""
+    target = np.asarray(res.target)
+    carbon = np.asarray(res.carbon_g)
+    exec_region = np.asarray(res.exec_region)
+    shed_a = getattr(state, "shed", None)
+    shed = np.zeros(len(target), bool) if shed_a is None else np.asarray(
+        shed_a)
+    defer_a = getattr(state, "defer_hours", None)
+    out = dict(
+        counts=np.bincount((exec_region * 3 + target)[~shed],
+                           minlength=n_regions * 3).reshape(n_regions, 3),
+        total_carbon_g=carbon.sum(dtype=np.float32),
+        routed_carbon_g=carbon[~shed].sum(dtype=np.float32),
+        infeasible_count=int((~np.asarray(res.feasible)).sum()),
+        shed_count=int(shed.sum()),
+        spilled_count=int(((exec_region != region) & ~shed).sum()),
+        deferred_count=0, mean_defer_hours=0.0)
+    if defer_a is not None:
+        defer = np.asarray(defer_a)
+        dmask = (defer > 0) & ~shed
+        out["deferred_count"] = int(dmask.sum())
+        out["mean_defer_hours"] = defer[dmask].sum() / max(dmask.sum(), 1)
+    return out
+
+
+def _assert_host_aggregates(res, state, region, n_regions=N_REGIONS):
+    """Integer aggregates exactly the host formulas', float ones to
+    rtol 1e-5."""
+    want = _host_aggregates(res, state, region, n_regions)
+    for k in ("counts", "infeasible_count", "shed_count", "spilled_count",
+              "deferred_count"):
+        np.testing.assert_array_equal(np.asarray(getattr(res, k)), want[k],
+                                      err_msg=k)
+    for k in ("total_carbon_g", "routed_carbon_g", "mean_defer_hours"):
+        np.testing.assert_allclose(float(getattr(res, k)), float(want[k]),
+                                   rtol=1e-5, err_msg=k)
+
+
+class TestDeviceAggregation:
+    """The sharded call keeps its per-row results on the mesh and sums the
+    aggregates there: what it returns is what the host formulas give from
+    the same rows, and the reference sums are the single-device
+    program's."""
+
+    @pytest.mark.parametrize("name", ["placement-cross", "temporal-joint"])
+    def test_per_row_results_stay_on_the_mesh(self, cfg, base, mesh1, name):
+        fr = _routers(cfg, base)[name]
+        batch, region, t = _stream(257, seed=3, slack=True)
+        res, state = fr.route_stream_with_state(batch, region, t, mesh=mesh1)
+        fields = dict(target=res.target, carbon_g=res.carbon_g,
+                      shed=state.shed, exec_region=res.exec_region)
+        if name.startswith("temporal"):
+            fields["exec_hour"] = state.exec_hour
+        for k, a in fields.items():
+            assert isinstance(a, jax.Array), k
+            assert a.sharding == NamedSharding(mesh1, P("data")), (
+                k, a.sharding)
+
+    @pytest.mark.parametrize("name", ["oracle", "placement-diag",
+                                      "placement-cross",
+                                      "placement-uncapped",
+                                      "temporal-joint", "temporal-diag"])
+    def test_device_aggregates_match_host_formulas(self, cfg, base, mesh1,
+                                                   name):
+        fr = _routers(cfg, base, 1.0)[name]  # sheds, spills and defers
+        batch, region, t = _stream(257, seed=3, slack=True)
+        ref, _ = fr.route_stream_with_state(batch, region, t)
+        res, state = fr.route_stream_with_state(batch, region, t, mesh=mesh1)
+        _assert_host_aggregates(res, state, region)
+        for k in ("latency_opt_carbon_g", "energy_opt_carbon_g",
+                  "oracle_carbon_g"):
+            np.testing.assert_allclose(float(getattr(res, k)),
+                                       float(getattr(ref, k)), rtol=1e-5,
+                                       err_msg=k)
+
+
+MESH_AGGREGATES = r"""
+import json, os
+os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
+                           + os.environ.get("XLA_FLAGS", ""))
+import numpy as np, jax
+from jax.sharding import Mesh
+from repro.configs import get_config
+from repro.core.carbon_intensity import DEFAULT_REGIONS, CarbonGrid
+from repro.serve import (FleetRouter, OraclePolicy, PlacementPolicy,
+                         RequestBatch, TemporalPolicy)
+
+cfg = get_config("h2o-danube-1.8b", smoke=True)
+R = len(DEFAULT_REGIONS)
+caps = np.full((R, 3), 1.0)  # sheds, spills and defers
+xgrid = CarbonGrid.fully_connected(DEFAULT_REGIONS)
+infra = FleetRouter(cfg).infra
+routers = {
+    "placement": FleetRouter(cfg, grid=xgrid, policy=PlacementPolicy(
+        OraclePolicy(infra), caps)),
+    "temporal": FleetRouter(cfg, grid=xgrid, policy=TemporalPolicy(
+        OraclePolicy(infra), caps, max_defer_h=6)),
+}
+out = []
+for n in (515, 512):
+    rng = np.random.default_rng(0)
+    batch = RequestBatch(
+        prompt_tokens=rng.integers(16, 512, n).astype(np.float64),
+        max_new_tokens=rng.integers(16, 256, n).astype(np.float64),
+        latency_budget_s=rng.uniform(0.3, 4.0, n),
+        bytes_per_token=np.full(n, 4.0),
+        available=rng.random((n, 3)) > 0.1,
+        slack_hours=rng.integers(0, 6, n).astype(np.float64))
+    region = rng.integers(0, R, n)
+    t = rng.uniform(0, 24, n)
+    for tag, fr in routers.items():
+        ref, _ = fr.route_stream_with_state(batch, region, t)
+        for d in (2, 4, 8):
+            mesh = Mesh(np.asarray(jax.devices()[:d]), ("data",))
+            res, state = fr.route_stream_with_state(batch, region, t,
+                                                    mesh=mesh)
+            rows = dict(target=res.target, carbon_g=res.carbon_g,
+                        shed=state.shed,
+                        exec_hour=getattr(state, "exec_hour", None))
+            row = dict(
+                tag=tag, n=n, d=d, region=region.tolist(),
+                placement={k: dict(
+                    devices=len(a.sharding.device_set),
+                    mesh=a.sharding.mesh == mesh,
+                    shards=sorted({s.data.shape[0]
+                                   for s in a.addressable_shards}))
+                    for k, a in rows.items() if a is not None},
+                ref={k: float(getattr(ref, k)) for k in (
+                    "latency_opt_carbon_g", "energy_opt_carbon_g",
+                    "oracle_carbon_g")})
+            for k in ("target", "carbon_g", "feasible", "exec_region",
+                      "counts", "total_carbon_g", "routed_carbon_g",
+                      "latency_opt_carbon_g", "energy_opt_carbon_g",
+                      "oracle_carbon_g", "infeasible_count", "shed_count",
+                      "spilled_count", "deferred_count",
+                      "mean_defer_hours"):
+                row[k] = np.asarray(getattr(res, k)).tolist()
+            for k in ("shed", "defer_hours"):
+                a = getattr(state, k, None)
+                row["state_" + k] = None if a is None else np.asarray(
+                    a).tolist()
+            out.append(row)
+print("MESH_AGGREGATES " + json.dumps(out))
+"""
+
+
+def test_mesh_aggregates_match_host_formulas_subprocess():
+    """At 2/4/8 fake devices, for capped cross-region placement and joint
+    temporal admission: per-row results stay split over the router's mesh
+    where the length is a device multiple (n = 512) and are replicated
+    over the mesh where it is not (n = 515), never held by one chip; the
+    aggregates summed on the device are the host formulas' from the
+    returned rows, and the reference sums the single-device program's."""
+    proc = subprocess.run([sys.executable, "-c", MESH_AGGREGATES],
+                          capture_output=True, text=True, timeout=560,
+                          env={**os.environ, "PYTHONPATH": "src",
+                               "JAX_PLATFORMS": "cpu"},
+                          cwd=os.path.dirname(os.path.dirname(__file__)))
+    line = [ln for ln in proc.stdout.splitlines()
+            if ln.startswith("MESH_AGGREGATES")]
+    assert line, proc.stderr[-2000:]
+    got = json.loads(line[0].split(" ", 1)[1])
+    assert len(got) == 2 * 2 * 3
+    assert all(row["shed_count"] and row["spilled_count"] for row in got)
+    for row in got:
+        case = (row["tag"], row["n"], row["d"])
+        even = row["n"] % row["d"] == 0
+        for k, p in row["placement"].items():
+            assert p["mesh"] and p["devices"] == row["d"], (case, k, p)
+            assert p["shards"] == [row["n"] // row["d"] if even
+                                   else row["n"]], (case, k, p)
+        res = SimpleNamespace(**{k: np.asarray(v) for k, v in row.items()
+                                 if not k.startswith("state_")})
+        state = SimpleNamespace(shed=np.asarray(row["state_shed"]),
+                                defer_hours=(
+                                    None if row["state_defer_hours"] is None
+                                    else np.asarray(row["state_defer_hours"])))
+        _assert_host_aggregates(res, state, np.asarray(row["region"]))
+        for k, v in row["ref"].items():
+            np.testing.assert_allclose(row[k], v, rtol=1e-5,
+                                       err_msg=f"{case} {k}")
 
 
 class TestShardedInvariants:

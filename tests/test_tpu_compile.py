@@ -109,19 +109,54 @@ def test_sharded_route_compiles_for_four_chips(topo):
     _compiles_sharded(topo, phase.build(), *phase.stream)
 
 
-def test_sharded_deferral_compiles_for_four_chips(topo):
-    """A day of 1,000,000 requests, half of them batch work that may wait
-    23 hours, on a 48-hour repeated grid through the shard_map program of
-    ``TemporalPolicy``: the (N, 24, 4, 3) candidate program fits each chip
-    only because the rows are split four ways."""
-    n, r = 1_000_000, 4
+def _deferral_day(n: int = 1_000_000, r: int = 4):
+    """The deferral day plan's router and stream: ``n`` requests, half of
+    them batch work that may wait 23 hours, on a 48-hour repeated grid."""
     cfg = get_config(chip_smoke.ARCH)
     policy = TemporalPolicy(OraclePolicy(FleetRouter(cfg).infra),
                             chip_smoke._dc_caps(r, n / (r * 24)),
                             max_defer_h=23)
     fr = FleetRouter(cfg, grid=chip_smoke._grid4().repeat(2), policy=policy)
-    _compiles_sharded(topo, fr, *deferrable_stream(n, r,
-                                                   slack_range_h=(23, 23)))
+    return fr, deferrable_stream(n, r, slack_range_h=(23, 23))
+
+
+def test_sharded_deferral_compiles_for_four_chips(topo):
+    """A day of 1,000,000 requests, half of them batch work that may wait
+    23 hours, on a 48-hour repeated grid through the shard_map program of
+    ``TemporalPolicy``: the (N, 24, 4, 3) candidate program fits each chip
+    only because the rows are split four ways."""
+    fr, stream = _deferral_day()
+    _compiles_sharded(topo, fr, *stream)
+
+
+def test_sharded_aggregation_compiles_for_four_chips(topo):
+    """The aggregation program that follows the deferral day's shard_map
+    program on a 4-chip mesh: it fits each chip, and every per-row result
+    leaves it split over the mesh, a quarter of the day on each chip."""
+    fr, (batch, region, t_hours) = _deferral_day()
+    n = len(batch)
+    hour = (np.floor(t_hours) % fr.grid.horizon_h).astype(np.int32)
+    region = np.asarray(region, np.int32)
+    axis = distributed.DATA_AXIS
+    mesh = Mesh(np.asarray(topo.devices[:4]), (axis,))
+    rows = NamedSharding(mesh, P(axis))
+    program = distributed._build_sharded_route(fr, mesh, axis)
+    streamed, inv_np = distributed.shard_stream(fr, batch, region, hour,
+                                                distributed.data_mesh(1))
+    per_row, tiled = _specs(jax.eval_shape(
+        program, *_specs(streamed, rows),
+        *_specs((fr._ci_table, fr._ci_fc), NamedSharding(mesh, P())),
+        None, None), rows)
+    refs = {k: per_row.pop(k)
+            for k in ("ref_latency", "ref_energy", "ref_oracle")}
+    aggregate = distributed._build_aggregate(fr, mesh, axis)
+    compiled = aggregate.lower(per_row, refs, tiled,
+                               *_specs((inv_np, region), rows)).compile()
+    _fits_one_chip(compiled)
+    res, state = compiled.output_shardings
+    for a in (res.target, res.carbon_g, res.feasible, res.exec_region,
+              state.shed, state.exec_hour, state.defer_hours):
+        assert a.shard_shape((n,)) == (n // 4,)
 
 
 def _kernel_cases():
