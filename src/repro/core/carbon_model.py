@@ -416,6 +416,16 @@ def total_cf_from_factors(f: EnergyFactors, ci: jax.Array) -> jax.Array:
             + f.emb_cf.sum(-1))
 
 
+def charged_ci(ci_table: jax.Array, home: jax.Array, exec_region: jax.Array,
+               exec_hour: jax.Array) -> jax.Array:
+    """(N, 5) CI rows a committed request is charged at, gathered from an
+    (R, H, 5) table at its execution hour: the device and access network
+    [mobile, edge_net] draw energy in its HOME region, the infrastructure
+    [edge_dc, core_net, hyper_dc] in the region it executes in."""
+    return jnp.concatenate([ci_table[home, exec_hour][:, :2],
+                            ci_table[exec_region, exec_hour][:, 2:]], axis=1)
+
+
 # --- Forecast-error risk on the factorized scorer ------------------------------
 #
 # Operational carbon is LINEAR in CI, so scoring a candidate on expected

@@ -336,11 +336,8 @@ def route_stream_rolling(fr, batch, region, t_hours, *, step_h: int = 6,
     w = batch.workload(fr.cfg)
     factors = carbon_model.energy_factors_batch(
         w, fr.infra, fr._interference, fr._net_slowdown)
-    actual = fr._ci_table
-    home_j = jnp.asarray(region_np)
-    er_j, eh_j = jnp.asarray(er), jnp.asarray(eh)
-    ci_exec = jnp.concatenate(
-        [actual[home_j, eh_j][:, :2], actual[er_j, eh_j][:, 2:]], axis=1)
+    ci_exec = carbon_model.charged_ci(fr._ci_table, jnp.asarray(region_np),
+                                      jnp.asarray(er), jnp.asarray(eh))
     cf = carbon_model.total_cf_from_factors(factors, ci_exec)
     carbon = np.asarray(jnp.take_along_axis(
         cf, jnp.asarray(tgt)[:, None], axis=1)[:, 0])

@@ -67,9 +67,8 @@ def _settle_carbon(w, infra, interference, net_slowdown, ci_table,
     the shared tables (infra, ci_table, …) live across calls and are not."""
     factors = carbon_model.energy_factors_batch(w, infra, interference,
                                                 net_slowdown)
-    ci_exec = jnp.concatenate(
-        [ci_table[home, eh][:, :2], ci_table[er, eh][:, 2:]], axis=1)
-    cf = carbon_model.total_cf_from_factors(factors, ci_exec)
+    cf = carbon_model.total_cf_from_factors(
+        factors, carbon_model.charged_ci(ci_table, home, er, eh))
     return jnp.take_along_axis(cf, tgt[:, None], axis=1)[:, 0]
 
 #: request lifecycle states (``RequestQueue.status`` values)

@@ -278,29 +278,6 @@ class GreenScaleRouter:
 _admit_windows_warned = False
 
 
-def pair_counts_and_deferral(exec_region, targets, shed, defer,
-                             n_regions: int):
-    """A call's (region, tier) counts over the rows not shed, its deferred
-    row count and their mean deferral in hours. ``shed`` and ``defer`` may
-    be None (nothing shed, nothing deferred). The counts are a one-hot
-    reduction over the flattened pair index — a dense sum, not an N-wide
-    scatter."""
-    pair = exec_region * N_TARGETS + targets
-    one_hot = jax.nn.one_hot(pair, n_regions * N_TARGETS, dtype=jnp.int32)
-    if shed is not None:
-        one_hot = one_hot * (~shed)[:, None].astype(jnp.int32)
-    counts = one_hot.sum(axis=0).reshape(n_regions, N_TARGETS)
-    if defer is None:
-        return counts, jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32)
-    dmask = defer > 0
-    if shed is not None:
-        dmask = dmask & ~shed
-    deferred = dmask.sum().astype(jnp.int32)
-    mean_defer = ((defer * dmask).sum()
-                  / jnp.maximum(deferred, 1)).astype(jnp.float32)
-    return counts, deferred, mean_defer
-
-
 def _warn_admit_windows() -> None:
     """Warn ONCE per process that bucketed admission is deprecated."""
     global _admit_windows_warned
@@ -387,6 +364,69 @@ class FleetRouteResult:
         return self.deferred_count / self.target.shape[0]
 
 
+#: the per-row reference carbons ``FleetRouter.route_rows`` returns: each
+#: row's latency-, energy- and carbon-optimal pick, charged at actuals
+REF_KEYS = ("ref_latency", "ref_energy", "ref_oracle")
+
+
+def aggregate_rows(rows: dict, region: jax.Array, n_regions: int,
+                   refs: dict | None = None) -> FleetRouteResult:
+    """A call's ``FleetRouteResult`` from ``FleetRouter.route_rows``'
+    per-row results in stream order, with ``region`` the home column.
+    ``refs`` holds the three reference sums where the caller takes them
+    itself (the sharded call sums them before it unsorts); else they are
+    summed here from the rows. The (region, tier) counts are a one-hot
+    reduction over the flattened pair index (a dense sum, not an N-wide
+    scatter) over the rows not shed."""
+    target, carbon, feas, shed = (rows[k] for k in
+                                  ("target", "carbon", "feas", "shed"))
+    exec_region, defer = rows["exec_region"], rows["defer"]
+    if exec_region is region:  # executed on arrival: nothing moved
+        spilled = jnp.zeros((), jnp.int32)
+    else:
+        moved = exec_region != region
+        if shed is not None:
+            moved = moved & ~shed
+        spilled = moved.sum().astype(jnp.int32)
+    pair = exec_region * N_TARGETS + target
+    one_hot = jax.nn.one_hot(pair, n_regions * N_TARGETS, dtype=jnp.int32)
+    if shed is not None:
+        one_hot = one_hot * (~shed)[:, None].astype(jnp.int32)
+    counts = one_hot.sum(axis=0).reshape(n_regions, N_TARGETS)
+    if defer is None:
+        deferred = jnp.zeros((), jnp.int32)
+        mean_defer = jnp.zeros((), jnp.float32)
+    else:
+        dmask = defer > 0
+        if shed is not None:
+            dmask = dmask & ~shed
+        deferred = dmask.sum().astype(jnp.int32)
+        mean_defer = ((defer * dmask).sum()
+                      / jnp.maximum(deferred, 1)).astype(jnp.float32)
+    total = carbon.sum()
+    routed = total if shed is None else (carbon * ~shed).sum()
+    if refs is None:
+        refs = {k: rows[k].sum() for k in REF_KEYS}
+    return FleetRouteResult(
+        target=target,
+        carbon_g=carbon,
+        feasible=feas,
+        counts=counts,
+        total_carbon_g=total,
+        routed_carbon_g=routed,
+        latency_opt_carbon_g=refs["ref_latency"],
+        energy_opt_carbon_g=refs["ref_energy"],
+        oracle_carbon_g=refs["ref_oracle"],
+        infeasible_count=(~feas).sum().astype(jnp.int32),
+        shed_count=(jnp.zeros((), jnp.int32) if shed is None
+                    else shed.sum().astype(jnp.int32)),
+        exec_region=exec_region,
+        spilled_count=spilled,
+        deferred_count=deferred,
+        mean_defer_hours=mean_defer,
+    )
+
+
 @dataclasses.dataclass
 class FleetRouter:
     """Route a (region, time)-tagged request stream against regional grids.
@@ -463,25 +503,7 @@ class FleetRouter:
         bind = getattr(self.policy, "bind_grid", None)
         if bind is not None:
             bind(self.grid)
-        policy = self.policy
-        infra = self._infra
         n_regions = len(self.regions)
-        interference = self._interference
-        net_slowdown = self._net_slowdown
-        # Factorized hot path: policies that score candidate (region, hour)
-        # placements via the einsum evaluator (cross-region PlacementPolicy,
-        # TemporalPolicy) get ONE Table-1 evaluation per batch — factors feed
-        # the routing outputs, the policy's candidate scores, AND the
-        # executed-placement accounting (no out_exec re-evaluation). The
-        # default path keeps the sweep program bit-for-bit.
-        use_factors = bool(getattr(self.policy, "wants_factors", False))
-        rtt_s = self.grid.rtt_s
-        # Forecast/actual split (host-static): with a forecast attached the
-        # policy DECIDES on the forecast view while routed carbon is CHARGED
-        # at actuals; without one, ``ci_fc`` is the very same buffer as
-        # ``ci_table`` and the whole split compiles away — the historical
-        # program, bit-for-bit.
-        split = self.grid.ci_forecast is not None
 
         # Donate the per-stream buffers (workload columns, region/hour,
         # order/inv_order, slack): every caller rebuilds them from host
@@ -496,138 +518,11 @@ class FleetRouter:
                          order: jax.Array, inv_order: jax.Array,
                          slack: jax.Array, cap_scale, used0
                          ) -> tuple[FleetRouteResult, object]:
-            env = Environment(ci=ci_fc[region, hour],  # (N, 5) forecast view
-                              interference=interference,
-                              net_slowdown=net_slowdown)
-            # Table-1 evaluation supplies the carbon/QoS accounting and the
-            # three reference objectives; the policy makes the decision
-            # (oracle-family policies reuse ``out`` via the outputs hint, so
-            # the default path is the pre-policy program, bit-for-bit).
-            if use_factors:
-                factors = carbon_model.energy_factors_batch(
-                    w, infra, interference, net_slowdown)
-                out = carbon_model.route_many_from_factors(
-                    factors, w, env.ci, avail)
-            else:
-                factors = None
-                out = carbon_model.route_many_envs(w, infra, env, avail)
-            # settle-at-actuals hook: what a (N,) target vector COSTS on the
-            # actual table at the arrival (region, hour). QoS feasibility is
-            # CI-free, so only carbon re-prices under the split.
-            if not split:
-                take_act = lambda t: jnp.take_along_axis(
-                    out.total_cf, t[:, None], axis=1)[:, 0]
-            elif factors is not None:
-                cf_act = carbon_model.total_cf_from_factors(
-                    factors, ci_table[region, hour])
-                take_act = lambda t: jnp.take_along_axis(
-                    cf_act, t[:, None], axis=1)[:, 0]
-            else:
-                out_act = carbon_model.route_many_envs(
-                    w, infra,
-                    Environment(ci=ci_table[region, hour],
-                                interference=interference,
-                                net_slowdown=net_slowdown), avail)
-                take_act = lambda t: jnp.take_along_axis(
-                    out_act.total_cf, t[:, None], axis=1)[:, 0]
-            targets, new_state = policy.decide(
-                w, env, avail, state, region=region, hour=hour, outputs=out,
-                order=order, inv_order=inv_order, slack=slack,
-                factors=factors, fc_table=ci_fc, cap_scale=cap_scale,
-                used0=used0)
-            # executed-placement accounting and the call's aggregates
+            rows, new_state = self.route_rows(
+                w, avail, region, hour, ci_table, ci_fc, state, order,
+                inv_order, slack, cap_scale, used0)
             with jax.named_scope("account"):
-                shed = getattr(new_state, "shed", None)
-                exec_region = getattr(new_state, "exec_region", None)
-                exec_hour = getattr(new_state, "exec_hour", None)
-                take = lambda o, t: jnp.take_along_axis(
-                    o.total_cf, t[:, None], axis=1)[:, 0]
-                take2 = lambda a, t: jnp.take_along_axis(
-                    a, t[:, None], axis=1)[:, 0]
-                if exec_region is None and exec_hour is None:
-                    # no cross-region / deferred placement: execute on arrival,
-                    # charged at the arrival cell's ACTUAL CI
-                    exec_region = region
-                    spilled = jnp.zeros((), jnp.int32)
-                    carbon = take_act(targets)
-                    feas = take2(out.ok, targets)
-                elif factors is not None:
-                    # executed-placement accounting on the factorized evaluator:
-                    # CI rows gathered at the EXECUTING (region, hour) — home
-                    # [mobile, edge_net] components stay billed in the home
-                    # region at the execution hour (the device draws energy when
-                    # the work actually runs), the WAN hop enters the QoS check
-                    # — and the precomputed factors turn them into carbon with
-                    # one einsum instead of the out_exec Table-1 re-evaluation.
-                    er = region if exec_region is None else exec_region
-                    eh = hour if exec_hour is None else exec_hour
-                    exec_region = er
-                    ci_exec = jnp.concatenate(
-                        [ci_table[region, eh][:, :2],
-                         ci_table[er, eh][:, 2:]], axis=1)
-                    cf_exec = carbon_model.total_cf_from_factors(factors, ci_exec)
-                    ok_exec = carbon_model.qos_feasible_from_factors(
-                        factors, w, rtt_s[region, er]) & avail
-                    carbon = take2(cf_exec, targets)
-                    feas = take2(ok_exec, targets)
-                    moved = er != region
-                    if shed is not None:
-                        moved = moved & ~shed
-                    spilled = moved.sum().astype(jnp.int32)
-                else:
-                    # legacy sweep path (non-factorizable inner policies):
-                    # carbon/QoS accounting under the EXECUTING region's CI for
-                    # rows that moved; unmoved rows keep the home-region values
-                    # bit-for-bit (adjacency == I parity with tier-only spill).
-                    # Only the infrastructure relocates: the device and access
-                    # network still draw energy in the HOME region, so the
-                    # executing env mixes home [mobile, edge_net] CI with the
-                    # executing region's [edge_dc, core_net, hyper_dc] — the
-                    # same mixing PlacementPolicy.pair_scores decides with.
-                    # Home components come from the ACTUAL table (== env.ci
-                    # without a forecast — the historical values bit-for-bit).
-                    ci_exec = jnp.concatenate(
-                        [ci_table[region, hour][:, :2],
-                         ci_table[exec_region, hour][:, 2:]],
-                        axis=1)
-                    env_exec = Environment(ci=ci_exec,
-                                           interference=interference,
-                                           net_slowdown=net_slowdown)
-                    out_exec = carbon_model.route_many_envs(w, infra, env_exec,
-                                                            avail)
-                    moved = exec_region != region
-                    if shed is not None:
-                        moved = moved & ~shed
-                    spilled = moved.sum().astype(jnp.int32)
-                    carbon = jnp.where(moved, take(out_exec, targets),
-                                       take_act(targets))
-                    feas = jnp.where(moved, take2(out_exec.ok, targets),
-                                     take2(out.ok, targets))
-                counts, deferred, mean_defer = pair_counts_and_deferral(
-                    exec_region, targets, shed,
-                    getattr(new_state, "defer_hours", None), n_regions)
-                return FleetRouteResult(
-                    target=targets,
-                    carbon_g=carbon,
-                    feasible=feas,
-                    counts=counts,
-                    total_carbon_g=carbon.sum(),
-                    routed_carbon_g=(carbon.sum() if shed is None
-                                     else (carbon * ~shed).sum()),
-                    # reference baselines decide on the forecast view too (they
-                    # are schedulers, not oracles-with-hindsight), but are
-                    # charged at actuals like everything else
-                    latency_opt_carbon_g=take_act(out.target_latency).sum(),
-                    energy_opt_carbon_g=take_act(out.target_energy).sum(),
-                    oracle_carbon_g=take_act(out.target).sum(),
-                    infeasible_count=(~feas).sum().astype(jnp.int32),
-                    shed_count=(jnp.zeros((), jnp.int32) if shed is None
-                                else shed.sum().astype(jnp.int32)),
-                    exec_region=exec_region,
-                    spilled_count=spilled,
-                    deferred_count=deferred,
-                    mean_defer_hours=mean_defer,
-                ), new_state
+                return aggregate_rows(rows, region, n_regions), new_state
 
         self._fleet_route = _fleet_route
 
@@ -636,6 +531,137 @@ class FleetRouter:
         """Packed ``InfraParams`` of this router's fleet — the public handle
         for building policies: ``OraclePolicy(router.infra, ...)``."""
         return self._infra
+
+    def route_rows(self, w: Workload, avail: jax.Array, region: jax.Array,
+                   hour: jax.Array, ci_table: jax.Array, ci_fc: jax.Array,
+                   state, order, inv_order, slack: jax.Array, cap_scale,
+                   used0, *, axis_name: str | None = None
+                   ) -> tuple[dict, object]:
+        """The routing program's per-row core, traced by the one-chip
+        program (``_fleet_route``) and, on each shard of a mesh, by the
+        sharded one (``repro.serve.distributed``, whose mesh axis
+        ``axis_name`` names). It evaluates Table 1 on the forecast view,
+        lets the policy decide, and charges each row's executed placement
+        at actuals. Returns ``(rows, new_state)``: ``rows`` maps target,
+        carbon, feas, exec_region, shed, exec_hour, defer and the
+        ``REF_KEYS`` to (N,) columns in the order of the inputs (None where
+        the policy keeps no such column), the policy's final state beside
+        them."""
+        policy = self.policy
+        infra = self._infra
+        env_of = lambda ci: Environment(ci=ci,
+                                        interference=self._interference,
+                                        net_slowdown=self._net_slowdown)
+        env = env_of(ci_fc[region, hour])  # (N, 5) forecast view
+        # Table-1 evaluation supplies the carbon/QoS accounting and the
+        # three reference objectives; the policy makes the decision
+        # (oracle-family policies reuse ``out`` via the outputs hint). A
+        # policy that scores candidate (region, hour) placements with the
+        # einsum evaluator (cross-region PlacementPolicy, TemporalPolicy)
+        # gets ONE factor evaluation that feeds the outputs, its candidate
+        # scores and the executed-placement accounting.
+        if getattr(policy, "wants_factors", False):
+            factors = carbon_model.energy_factors_batch(
+                w, infra, self._interference, self._net_slowdown)
+            out = carbon_model.route_many_from_factors(
+                factors, w, env.ci, avail)
+        else:
+            factors = None
+            out = carbon_model.route_many_envs(w, infra, env, avail)
+        take = lambda a, t: jnp.take_along_axis(a, t[:, None], axis=1)[:, 0]
+        # (N, 3) carbon of each tier at the arrival cell's ACTUAL CI. With
+        # no forecast on the grid ``ci_fc`` is ``ci_table``, so that is
+        # ``out``'s own; QoS feasibility is CI-free and never re-prices.
+        if self.grid.ci_forecast is None:
+            cf_act = out.total_cf
+        elif factors is not None:
+            cf_act = carbon_model.total_cf_from_factors(
+                factors, ci_table[region, hour])
+        else:
+            cf_act = carbon_model.route_many_envs(
+                w, infra, env_of(ci_table[region, hour]), avail).total_cf
+        targets, new_state = policy.decide(
+            w, env, avail, state, region=region, hour=hour, outputs=out,
+            order=order, inv_order=inv_order, slack=slack, factors=factors,
+            fc_table=ci_fc, cap_scale=cap_scale, used0=used0,
+            axis_name=axis_name)
+        # executed-placement accounting, charged at actuals
+        with jax.named_scope("account"):
+            shed = getattr(new_state, "shed", None)
+            exec_region = getattr(new_state, "exec_region", None)
+            exec_hour = getattr(new_state, "exec_hour", None)
+            if exec_region is None and exec_hour is None:
+                # no cross-region / deferred placement: execute on arrival
+                exec_region = region
+                carbon = take(cf_act, targets)
+                feas = take(out.ok, targets)
+            elif factors is not None:
+                # the factors turn the executing cell's CI rows into carbon
+                # with one einsum; the WAN hop enters the QoS check
+                er = region if exec_region is None else exec_region
+                eh = hour if exec_hour is None else exec_hour
+                exec_region = er
+                cf_exec = carbon_model.total_cf_from_factors(
+                    factors, carbon_model.charged_ci(ci_table, region, er, eh))
+                ok_exec = carbon_model.qos_feasible_from_factors(
+                    factors, w, self.grid.rtt_s[region, er]) & avail
+                carbon = take(cf_exec, targets)
+                feas = take(ok_exec, targets)
+            else:
+                # legacy sweep (non-factorizable inner policies): rows that
+                # moved are re-evaluated at the executing cell; unmoved rows
+                # keep the home values bit-for-bit (adjacency == I parity
+                # with tier-only spill)
+                out_exec = carbon_model.route_many_envs(
+                    w, infra, env_of(carbon_model.charged_ci(
+                        ci_table, region, exec_region, hour)), avail)
+                moved = exec_region != region
+                if shed is not None:
+                    moved = moved & ~shed
+                carbon = jnp.where(moved, take(out_exec.total_cf, targets),
+                                   take(cf_act, targets))
+                feas = jnp.where(moved, take(out_exec.ok, targets),
+                                 take(out.ok, targets))
+            # the reference baselines decide on the forecast view too (they
+            # are schedulers, not oracles with hindsight), but are charged
+            # at actuals like everything else
+            rows = dict(target=targets, carbon=carbon, feas=feas,
+                        exec_region=exec_region, shed=shed,
+                        exec_hour=exec_hour,
+                        defer=getattr(new_state, "defer_hours", None),
+                        ref_latency=take(cf_act, out.target_latency),
+                        ref_energy=take(cf_act, out.target_energy),
+                        ref_oracle=take(cf_act, out.target))
+        return rows, new_state
+
+    def stream_order(self, region_np: np.ndarray, hour_np: np.ndarray
+                     ) -> tuple[np.ndarray, np.ndarray] | None:
+        """The admission-segment stream order on the host, as ``(order,
+        inverse)`` int32 permutations, or None for a policy without a
+        ``stream_order_key`` (a per-row policy must not pay an O(N log N)
+        sort it never consumes). A stable sort by arrival window — or by
+        (window, home region) for ``"window_region"`` (tier-only
+        PlacementPolicy) — with the policy's own window count (else the
+        horizon), so the sort stays segment-contiguous for any
+        ``n_windows``."""
+        order_key = getattr(self.policy, "stream_order_key", None)
+        if order_key is None:
+            return None
+        win = hour_np % (getattr(self.policy, "n_windows", None)
+                         or self._horizon_h)
+        key = (win * len(self.regions) + region_np
+               if order_key == "window_region" else win)
+        order = np.argsort(key, kind="stable").astype(np.int32)
+        inv = np.empty_like(order)
+        inv[order] = np.arange(len(order), dtype=np.int32)
+        return order, inv
+
+    def ci_tables(self, ci_fc: jax.Array | None = None
+                  ) -> tuple[jax.Array, jax.Array]:
+        """The (R, H, 5) CI tables a routing call charges at and decides
+        on: the actuals, and ``ci_fc`` or else the grid's forecast view
+        (the same buffer as the actuals when no forecast is attached)."""
+        return self._ci_table, self._ci_fc if ci_fc is None else ci_fc
 
     def env_at(self, region: int, hour: int) -> Environment:
         """The exact Environment a request in ``region`` at ``hour`` sees
@@ -715,34 +741,19 @@ class FleetRouter:
                     slack_np: np.ndarray | None = None) -> tuple:
         """The single-device ``_fleet_route`` arguments for a prepared
         stream (what ``_route_arrays`` calls it with; their shapes are what
-        an ahead-of-time compile for a described device needs)."""
-        # stream-order hint: stable radix sort by arrival window — or by
-        # (window, home region) when the policy wants finer segments
-        # (tier-only PlacementPolicy) — on the host; only computed for
-        # policies that declare a ``stream_order_key`` (the default path
-        # must not pay an O(N log N) sort it never consumes). The window
-        # key honours the policy's own window count so the sort stays
-        # segment-contiguous for n_windows != 24 too.
-        order_key = getattr(self.policy, "stream_order_key", None)
-        if order_key is None:
-            order = inv_order = None
-        else:
-            n_win = getattr(self.policy, "n_windows", None) or self._horizon_h
-            win_np = hour_np % n_win
-            key = (win_np * len(self.regions) + region_np
-                   if order_key == "window_region" else win_np)
-            order_np = np.argsort(key, kind="stable").astype(np.int32)
-            inv_np = np.empty_like(order_np)
-            inv_np[order_np] = np.arange(len(order_np), dtype=np.int32)
-            order, inv_order = jnp.asarray(order_np), jnp.asarray(inv_np)
+        an ahead-of-time compile for a described device needs). The
+        stream-order hint leaves the permutation itself to the device."""
+        perm = self.stream_order(region_np, hour_np)
+        order, inv_order = ((None, None) if perm is None
+                            else map(jnp.asarray, perm))
         region = jnp.asarray(region_np)
         hour = jnp.asarray(hour_np)
         slack = jnp.asarray(batch.slack_h if slack_np is None else
                             np.asarray(slack_np, np.int32))
         state = self.policy.initial_state(len(self.regions), len(batch))
         return (batch.workload(self.cfg), batch.avail, region, hour,
-                self._ci_table, self._ci_fc if ci_fc is None else ci_fc,
-                state, order, inv_order, slack, cap_scale, used0)
+                *self.ci_tables(ci_fc), state, order, inv_order, slack,
+                cap_scale, used0)
 
     def route_stream_rolling(self, batch: RequestBatch, region: np.ndarray,
                              t_hours: np.ndarray, *, step_h: int = 6,

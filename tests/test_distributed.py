@@ -93,6 +93,13 @@ def _routers(cfg, base, cap: float = 30.0):
             OraclePolicy(base.infra), caps, max_defer_h=6)),
         "temporal-diag": mk(policy=TemporalPolicy(
             OraclePolicy(base.infra), caps, max_defer_h=6)),
+        # decide on a forecast, charge at actuals
+        "placement-forecast": mk(
+            grid=xgrid.forecast_from_actual(sigma_h=0.03),
+            policy=PlacementPolicy(OraclePolicy(base.infra), caps)),
+        # the Table-1 sweep per candidate region, not the factors
+        "placement-legacy": mk(grid=xgrid, policy=PlacementPolicy(
+            OraclePolicy(base.infra), caps, factorized=False)),
     }
 
 
@@ -132,7 +139,9 @@ class TestShardedParity:
     @pytest.mark.parametrize("name", ["oracle", "placement-diag",
                                       "placement-cross",
                                       "placement-uncapped",
-                                      "temporal-joint", "temporal-diag"])
+                                      "temporal-joint", "temporal-diag",
+                                      "placement-forecast",
+                                      "placement-legacy"])
     def test_mesh_matches_single_device(self, cfg, base, mesh1, name):
         fr = _routers(cfg, base)[name]
         batch, region, t = _stream(257, seed=3, slack=True)  # non-pow2 n
